@@ -38,6 +38,11 @@ import jax.numpy as jnp
 Array = jax.Array
 
 _EPS = 1e-12
+# Gram-form dots run at full f32 precision (same choice as
+# ``repro.kernels.ref.PRECISION``): a TPU f32 dot at default precision is a
+# single bf16 pass, and xx + yy - 2g amplifies that error past the gaps
+# between near neighbours.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +121,8 @@ def _sqeuclidean_gram(X: Array, Y: Array) -> Array:
     # clamps for the residual cancellation.
     xx = jnp.sum(X.astype(jnp.float32) ** 2, axis=-1)
     yy = jnp.sum(Y.astype(jnp.float32) ** 2, axis=-1)
-    g = jnp.einsum("md,nd->mn", X, Y, preferred_element_type=jnp.float32)
+    g = jnp.einsum("md,nd->mn", X, Y, preferred_element_type=jnp.float32,
+                   precision=_HIGHEST)
     return jnp.maximum(xx[:, None] + yy[None, :] - 2.0 * g, 0.0)
 
 
@@ -127,13 +133,13 @@ def _euclidean_pairwise(X: Array, Y: Array) -> Array:
 def _cosine_pairwise(X: Array, Y: Array) -> Array:
     xn = jnp.sqrt(jnp.maximum(jnp.sum(X.astype(jnp.float32) ** 2, axis=-1), _EPS))
     yn = jnp.sqrt(jnp.maximum(jnp.sum(Y.astype(jnp.float32) ** 2, axis=-1), _EPS))
-    cos = jnp.einsum("md,nd->mn", X, Y,
-                     preferred_element_type=jnp.float32) / (xn[:, None] * yn[None, :])
+    cos = jnp.einsum("md,nd->mn", X, Y, preferred_element_type=jnp.float32,
+                     precision=_HIGHEST) / (xn[:, None] * yn[None, :])
     return 1.0 - jnp.clip(cos, -1.0, 1.0)
 
 
 def _dot_pairwise(X: Array, Y: Array) -> Array:
-    return -(X @ Y.T)
+    return -jnp.matmul(X, Y.T, precision=_HIGHEST)
 
 
 def _minkowski_pairwise(p: float):

@@ -53,32 +53,6 @@ from repro.kernels import ref as kref
 
 Array = jax.Array
 
-try:  # jax >= 0.6 top-level API
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
-def axis_size(axis_name: str) -> int:
-    """Static mesh-axis size from inside shard_map (jax < 0.6 compat:
-    ``lax.axis_size`` does not exist there; ``psum(1, axis)`` is static).
-    Public: the model layer's sharded retrieval uses it too."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # ---------------------------------------------------------------------------
 # Global top-k merge collectives
 # ---------------------------------------------------------------------------
@@ -102,7 +76,7 @@ def topk_merge_butterfly(dists: Array, ids: Array, axis_name: str, k: int):
     sub-cube; after log2(P) rounds all devices hold the global top-k
     (replicated). Requires a power-of-two axis size.
     """
-    Pn = axis_size(axis_name)
+    Pn = jax.lax.axis_size(axis_name)
     if Pn & (Pn - 1):
         raise ValueError(f"butterfly merge needs power-of-two axis, got {Pn}")
     rounds = int(math.log2(Pn))
@@ -142,7 +116,7 @@ def _shard_index(axes: Sequence[str]):
     """Linear shard index across (possibly several) mesh axes."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -205,7 +179,8 @@ def build_sharded(
         jax.ShapeDtypeStruct((1, per, d), jnp.float32),
     )
     out_spec = jax.tree.map(lambda _: P(tuple(db_axes)), shape_tree)
-    fn = shard_map(body, mesh, in_specs=(spec_in,), out_specs=out_spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec_in,),
+                       out_specs=out_spec, check_vma=False)
     return fn(data.reshape(Pn, per, d).astype(jnp.float32))
 
 
@@ -275,7 +250,8 @@ def _sharded_search_fn(
         in_specs.append(P(db_axes))  # mask sharded like the index
     out_specs = nsa.SearchResult(dists=P(), ids=P(), n_candidates=P())
     return jax.jit(
-        shard_map(body, mesh, in_specs=tuple(in_specs), out_specs=out_specs)
+        jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                      out_specs=out_specs, check_vma=False)
     )
 
 
@@ -455,11 +431,12 @@ def scan_quantized_sharded(
     if slot_valid is not None:
         in_specs.append(P(tuple(db_axes)))
         args.append(jnp.asarray(slot_valid))
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),
+        check_vma=False,
     )
     return fn(*args)
 
@@ -547,10 +524,11 @@ def exact_knn_sharded(
         gids = idx.astype(jnp.int32) + shard * jnp.int32(per)
         return topk_merge(-neg, gids, tuple(db_axes), k, method=merge)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(P(tuple(db_axes), None, None), P()),
         out_specs=(P(), P()),
+        check_vma=False,
     )
     return fn(DB.reshape(Pn, per, d), jnp.asarray(Q, jnp.float32))

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import tiling
+from repro.kernels.ref import PRECISION
 
 Array = jax.Array
 
@@ -67,7 +68,8 @@ def _sweep_kernel(d_ref, d1_ref, d2_ref, n1_ref, v_ref, o_ref, *, kp):
 
     # T contribution (MXU) + this tile's S partial broadcast onto every slot.
     o_ref[...] += (
-        jnp.dot(onehot.T, t, preferred_element_type=jnp.float32)
+        jnp.dot(onehot.T, t, preferred_element_type=jnp.float32,
+                precision=PRECISION)
         + jnp.sum(gain, axis=0, keepdims=True)
     )
 

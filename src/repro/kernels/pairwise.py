@@ -18,7 +18,7 @@ Two kernels, selected by distance *form* (see ``repro.kernels.ref``):
 ``_vpu_kernel``  (l1 / chebyshev)
     Same grid; no matmul form exists, so each step materialises the
     ``[bm, bn, bd]`` difference cube *in VMEM only* (never HBM) and reduces it
-    on the VPU. ``bd`` is kept small (default 64) so the cube fits VMEM.
+    on the VPU. The row tile ``bm`` shrinks until the cube fits VMEM.
 
 Both kernels accumulate in f32 regardless of input dtype (bf16 inputs hit the
 MXU natively in the gram path). Grid dims are ``(parallel, parallel,
@@ -40,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tiling
-from repro.kernels.ref import FORMS, GRAM_FORMS, VPU_FORMS
+from repro.kernels.ref import FORMS, GRAM_FORMS, PRECISION
 
 Array = jax.Array
 
@@ -67,8 +67,12 @@ def _gram_kernel(x_ref, y_ref, xx_ref, yy_ref, o_ref, acc_ref, *, form, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    x, y = x_ref[...], y_ref[...]
+    # f32 tiles take the full-precision passes; bf16 products are exact in
+    # f32 already, and Mosaic refuses an fp32 contract precision for them.
+    prec = PRECISION if x.dtype == jnp.float32 else None
     acc_ref[...] += jnp.dot(
-        x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32
+        x, y.T, preferred_element_type=jnp.float32, precision=prec
     )
 
     @pl.when(kk == nk - 1)
@@ -136,18 +140,23 @@ def pairwise_pallas(
         raise ValueError(f"dim mismatch {d} vs {d2}")
 
     # Backend-real tiling: align the d (lane) axis and the m (sublane) axis
-    # to the input dtype's tile multiples, shrink blocks overhanging the
-    # (padded) problem, and bound the per-step VMEM footprint by halving bd
-    # — for the VPU forms that replaces the old fixed ``bd = min(bd, 64)``
-    # clamp with a budget the [bm, bn, bd] difference cube must actually fit.
+    # to the input dtype's tile multiples and shrink blocks overhanging the
+    # (padded) problem. ``bd`` is the lane axis of both input tiles, so it
+    # stays a multiple of 128 (or the whole padded d); the per-step VMEM
+    # budget — which the VPU forms' [bm, bn, bd] difference cube must fit —
+    # is met by halving the row tile ``bm`` (then ``bn``) instead.
     isize = X.dtype.itemsize
-    bm = tiling.shrink(bm, m, tiling.sublane(X.dtype))
+    sub = tiling.sublane(X.dtype)
+    bm = tiling.shrink(bm, m, sub)
     bn = tiling.shrink(bn, n, tiling.LANE)
     bd = tiling.shrink(bd, d, tiling.LANE)
-    bd = tiling.fit_budget(
-        bd,
-        lambda x: tiling.vmem_pairwise(form, bm, bn, x, isize),
-        floor=min(bd, tiling.LANE if form in GRAM_FORMS else 8),
+    bm = tiling.fit_budget(
+        bm, lambda x: tiling.vmem_pairwise(form, x, bn, bd, isize),
+        floor=min(bm, sub),
+    )
+    bn = tiling.fit_budget(
+        bn, lambda x: tiling.vmem_pairwise(form, bm, x, bd, isize),
+        floor=min(bn, tiling.LANE),
     )
 
     mp, np_, dp = _ceil_to(m, bm), _ceil_to(n, bn), _ceil_to(d, bd)

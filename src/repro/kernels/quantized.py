@@ -13,10 +13,10 @@ Structurally this is ``topk.rank_pallas`` with a dequantise prologue:
 
   grid = (b/bq, w/bn)          # candidate axis sequential ("arbitrary")
   per step, VMEM only:
-    c  = codes[bq, bn, d] * scales[bq, bn, 1]   # dequantise in-register
-    cc = sum(c*c, -1)                           # norms from dequantised tile
-    d  = dist(q_tile, c)                        # VPU rowwise reduction
-    merge running top-k of concat([state, d])   # one lax.top_k per tile
+    c  = codes[bq, bn, dc] * scales[bq, bn, 1]  # unpack to planes, dequantise
+    cc = sum(c*c, -1)                           # norms from dequantised planes
+    d  = dist(q_planes, c)                      # VPU rowwise reduction
+    state = merge_topk(state, d)                # topk.merge_topk
 
 Only the running ``[bq, k]`` top-k state persists (in the revisited output
 block); the [b, w] distance matrix never reaches HBM. Norm-consuming forms
@@ -37,33 +37,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import tiling
-from repro.kernels.ref import BIG, CODE_FORMATS, FORMS, NORM_FORMS
-from repro.kernels.topk import _ceil_to, _rank_tile_distance
+from repro.kernels.ref import BIG, CODE_FORMATS, FORMS
+from repro.kernels.topk import _ceil_to, _rank_tile_distance, merge_topk
 
 Array = jax.Array
 
 
-def _unpack_tile(c, fmt: str, d: int) -> Array:
-    """In-register unpack of a packed [bq, bn, dc] code tile to [bq, bn, d].
+# Feature planes per packed byte: plane p of a code tile holds dimensions
+# p, p + P, p + 2P, ... (int4: even / odd nibbles, binary: bit p of each byte).
+PLANES = {"dense": 1, "int4": 2, "binary": 8}
 
-    int4: two signed nibbles per byte (branchless xor/sub sign extension);
-    binary: eight sign bits per byte, mapped to ±1. All arithmetic is int32
-    — native VPU ops, no sub-word shuffles — and the unpacked tile exists
-    only in VMEM: HBM traffic stays at the packed width (0.5 / 0.125
-    bytes per dimension).
+
+def _unpack_planes(c, fmt: str, d: int):
+    """In-register unpack of a packed [bq, bn, dc] code tile into planes.
+
+    Yields ``PLANES[fmt]`` tiles of shape [bq, bn, dc]; plane ``p`` holds
+    dimensions ``p, p + P, ...`` and the wrapper splits the query the same
+    way, so the distance reduces plane by plane and no interleaving reshape
+    (which would pad a size-2 or size-8 minor axis out to 128 lanes) runs in
+    VMEM. int4: two signed nibbles per byte (branchless xor/sub sign
+    extension); binary: eight sign bits per byte, mapped to ±1, with the
+    padding bits past ``d`` zeroed. All arithmetic is int32 — native VPU
+    ops — and HBM traffic stays at the packed width (0.5 / 0.125 bytes per
+    dimension).
     """
     if fmt == "dense":
-        return c
+        yield c
+        return
     c32 = c.astype(jnp.int32) & 0xFF
     if fmt == "int4":
-        lo = ((c32 & 0xF) ^ 0x8) - 0x8
-        hi = ((c32 >> 4) ^ 0x8) - 0x8
-        full = jnp.stack([lo, hi], axis=-1).reshape(*c32.shape[:-1], -1)
-    else:  # binary
-        shifts = jnp.arange(8, dtype=jnp.int32)
-        bits = (c32[..., None] >> shifts) & 1
-        full = (2 * bits - 1).reshape(*c32.shape[:-1], -1)
-    return full[..., :d]
+        yield ((c32 & 0xF) ^ 0x8) - 0x8
+        yield ((c32 >> 4) ^ 0x8) - 0x8
+        return
+    byte = jax.lax.broadcasted_iota(jnp.int32, c32.shape, c32.ndim - 1)
+    for p in range(PLANES[fmt]):
+        sign = 2 * ((c32 >> p) & 1) - 1
+        yield sign if d % 8 == 0 else jnp.where(8 * byte + p < d, sign, 0)
 
 
 def _scan_kernel(q_ref, c_ref, s_ref, ok_ref, od_ref, oi_ref, *, form, k, bn,
@@ -76,20 +85,18 @@ def _scan_kernel(q_ref, c_ref, s_ref, ok_ref, od_ref, oi_ref, *, form, k, bn,
         oi_ref[...] = jnp.full_like(oi_ref, -1)
 
     # Unpack (packed formats) + dequantise the native-dtype code tile in
-    # VMEM: [bq, bn, d] f32, gone after the reduction below.
-    c = _unpack_tile(c_ref[...], fmt, d)
-    c = c.astype(jnp.float32) * s_ref[...].astype(jnp.float32)[:, :, None]
-    cc = jnp.sum(c * c, axis=-1) if form in NORM_FORMS else None
-    d = _rank_tile_distance(form, q_ref[...], c, cc)  # [bq, bn]
-    d = jnp.where(ok_ref[...] != 0, d, BIG)
-    bq = d.shape[0]
-    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
-
-    all_d = jnp.concatenate([od_ref[...], d], axis=1)  # [bq, k + bn]
-    all_i = jnp.concatenate([oi_ref[...], col], axis=1)
-    neg, idx = jax.lax.top_k(-all_d, k)
-    od_ref[...] = -neg
-    oi_ref[...] = jnp.take_along_axis(all_i, idx, axis=1)
+    # VMEM, plane by plane: a generator, so each f32 plane is reduced into
+    # the distance tile before the next one is made.
+    scale = s_ref[...].astype(jnp.float32)[:, :, None]
+    cs = (p.astype(jnp.float32) * scale
+          for p in _unpack_planes(c_ref[...], fmt, d))
+    qs = [q_ref[p] for p in range(PLANES[fmt])]
+    dist = _rank_tile_distance(form, qs, cs)  # [bq, bn]
+    # widen the int8 mask first: Mosaic cannot relayout an int8 compare
+    # onto the reduced distance tile ("Lane broadcast")
+    dist = jnp.where(ok_ref[...].astype(jnp.int32) != 0, dist, BIG)
+    od_ref[...], oi_ref[...] = merge_topk(od_ref[...], oi_ref[...], dist,
+                                          j * bn)
 
 
 @functools.partial(
@@ -131,18 +138,28 @@ def scan_pallas(
     if k > w:
         raise ValueError(f"k={k} > candidate width w={w}")
 
+    if C.dtype == jnp.float16:
+        # Mosaic loads no f16 vectors on TPU: widen fp16 codes before the
+        # call (exact; the gathered cube then moves at 4 bytes per element)
+        C = C.astype(jnp.float32)
+
     # Backend-real tiling: shrink blocks overhanging the (padded) problem
     # and bound the per-step VMEM cube (packed container + f32 unpack copy).
     bq = tiling.shrink(bq, b, tiling.sublane(jnp.float32))
     bn = tiling.shrink(bn, w, tiling.LANE)
     bn = tiling.fit_budget(
         bn,
-        lambda x: tiling.vmem_rank(bq, x, d, k, C.dtype.itemsize),
+        lambda x: tiling.vmem_rank(bq, x, dc, k, C.dtype.itemsize,
+                                   PLANES[fmt]),
         floor=min(bn, tiling.LANE),
     )
 
     bp, wp = _ceil_to(b, bq), _ceil_to(w, bn)
-    Qp = jnp.pad(Q, ((0, bp - b), (0, 0)))
+    # Query planes matching the unpacked code planes: [P, bp, dc].
+    n_planes = PLANES[fmt]
+    Qp = jnp.pad(Q.astype(jnp.float32),
+                 ((0, bp - b), (0, n_planes * dc - d)))
+    Qp = Qp.reshape(bp, dc, n_planes).transpose(2, 0, 1)
     Cp = jnp.pad(C, ((0, bp - b), (0, wp - w), (0, 0)))
     Sp = jnp.pad(scales.astype(jnp.float32), ((0, bp - b), (0, wp - w)))
     okp = jnp.pad(ok.astype(jnp.int8), ((0, bp - b), (0, wp - w)))
@@ -154,7 +171,7 @@ def scan_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((n_planes, bq, dc), lambda i, j: (0, i, 0)),
             pl.BlockSpec((bq, bn, dc), lambda i, j: (i, j, 0)),
             pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bq, bn), lambda i, j: (i, j)),

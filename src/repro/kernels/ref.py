@@ -43,6 +43,21 @@ FORM_OF = {
 
 _EPS = 1e-12
 BIG = 1e30
+# Precision of every Gram-form dot (XLA and in-kernel). On a TPU an f32 dot
+# at default precision runs as one bf16 pass; the xx + yy - 2g form then
+# loses the gaps between near neighbours. HIGHEST keeps f32 inputs at f32.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _gram(X: Array, Y: Array) -> Array:
+    """[m, d] x [n, d] -> [m, n] inner products at :data:`PRECISION`."""
+    return jnp.matmul(X, Y.T, precision=PRECISION)
+
+
+def _rowwise_gram(Q: Array, C: Array) -> Array:
+    """[b, d] x [b, w, d] -> [b, w] per-query inner products."""
+    return jnp.einsum("bd,bwd->bw", Q, C, preferred_element_type=jnp.float32,
+                      precision=PRECISION)
 
 
 def stream_cols(pairwise_fn, X: Array, Y: Array, chunk: int) -> Array:
@@ -92,15 +107,15 @@ def pairwise_ref(X: Array, Y: Array, form: str) -> Array:
     if form in ("sqeuclidean", "l2"):
         xx = jnp.sum(X * X, axis=-1)
         yy = jnp.sum(Y * Y, axis=-1)
-        d2 = jnp.maximum(xx[:, None] + yy[None, :] - 2.0 * (X @ Y.T), 0.0)
+        d2 = jnp.maximum(xx[:, None] + yy[None, :] - 2.0 * _gram(X, Y), 0.0)
         return d2 if form == "sqeuclidean" else jnp.sqrt(d2)
     if form == "cosine":
         xn = jnp.sqrt(jnp.maximum(jnp.sum(X * X, axis=-1), _EPS))
         yn = jnp.sqrt(jnp.maximum(jnp.sum(Y * Y, axis=-1), _EPS))
-        cos = (X @ Y.T) / (xn[:, None] * yn[None, :])
+        cos = _gram(X, Y) / (xn[:, None] * yn[None, :])
         return 1.0 - jnp.clip(cos, -1.0, 1.0)
     if form == "dot":
-        return -(X @ Y.T)
+        return -_gram(X, Y)
     if form == "l1":
         return jnp.sum(jnp.abs(X[:, None, :] - Y[None, :, :]), axis=-1)
     if form == "chebyshev":
@@ -194,18 +209,16 @@ def rowwise_ref(
         cc = jnp.sum(C * C, axis=-1)
     if form in ("sqeuclidean", "l2"):
         qq = jnp.sum(Q * Q, axis=-1)
-        g = jnp.einsum("bd,bwd->bw", Q, C, preferred_element_type=jnp.float32)
+        g = _rowwise_gram(Q, C)
         d2 = jnp.maximum(qq[:, None] + cc.astype(jnp.float32) - 2.0 * g, 0.0)
         return d2 if form == "sqeuclidean" else jnp.sqrt(d2)
     if form == "cosine":
         qn = jnp.sqrt(jnp.maximum(jnp.sum(Q * Q, axis=-1), _EPS))
         cn = jnp.sqrt(jnp.maximum(cc.astype(jnp.float32), _EPS))
-        cos = jnp.einsum(
-            "bd,bwd->bw", Q, C, preferred_element_type=jnp.float32
-        ) / (qn[:, None] * cn)
+        cos = _rowwise_gram(Q, C) / (qn[:, None] * cn)
         return 1.0 - jnp.clip(cos, -1.0, 1.0)
     if form == "dot":
-        return -jnp.einsum("bd,bwd->bw", Q, C, preferred_element_type=jnp.float32)
+        return -_rowwise_gram(Q, C)
     if form == "l1":
         return jnp.sum(jnp.abs(Q[:, None, :] - C), axis=-1)
     if form == "chebyshev":

@@ -19,6 +19,8 @@ scored with :func:`pad_waste` so ragged shapes penalise overhanging tiles.
 
 from __future__ import annotations
 
+import numpy as np
+
 LANE = 128  # minor-axis vector width (all dtypes)
 VMEM_BUDGET = 8 * 2 ** 20  # conservative per-kernel-step budget (~half VMEM)
 
@@ -41,12 +43,7 @@ def ceil_to(x: int, m: int) -> int:
 
 def sublane(dtype) -> int:
     """Minimum second-minor tile extent for ``dtype`` (f32 8, bf16 16, int8 32)."""
-    try:
-        size = dtype.itemsize
-    except AttributeError:  # a jnp scalar type, e.g. jnp.float32
-        import numpy as np
-
-        size = np.dtype(dtype).itemsize
+    size = np.dtype(dtype).itemsize
     return {4: 8, 2: 16, 1: 32}.get(size, 8)
 
 
@@ -93,10 +90,15 @@ def pad_waste(shape, blocks) -> float:
 
 
 def vmem_pairwise(form: str, bm: int, bn: int, bd: int, itemsize: int = 4) -> int:
-    """Gram: two input tiles + f32 scratch/out; VPU adds the [bm,bn,bd] cube."""
+    """Gram: two input tiles + f32 scratch/out; VPU adds the [bm,bn,bd] cube.
+
+    ``bd`` is the lane axis of both input tiles, so it stays a multiple of
+    128 (or the whole padded ``d``); the wrappers fit the budget by shrinking
+    ``bm`` / ``bn`` instead.
+    """
     tiles = (bm + bn) * bd * itemsize + 3 * bm * bn * 4
     if form in ("l1", "chebyshev"):
-        tiles += bm * bn * bd * 4
+        tiles += bm * bn * ceil_to(bd, LANE) * 4
     return tiles
 
 
@@ -104,9 +106,18 @@ def vmem_knn(bq: int, bn: int, d: int, k: int, itemsize: int = 4) -> int:
     return (bq + bn) * d * itemsize + 3 * bq * (k + bn) * 4
 
 
-def vmem_rank(bq: int, bn: int, d: int, k: int, itemsize: int = 4) -> int:
-    """Candidate cube in native dtype + its f32 dequantised/cast copy."""
-    return bq * bn * d * (itemsize + 4) + bq * d * 4 + 3 * bq * (k + bn) * 4
+def vmem_rank(bq: int, bn: int, d: int, k: int, itemsize: int = 4,
+              planes: int = 1) -> int:
+    """Candidate cube in native dtype + an f32 copy per unpacked plane.
+
+    ``d`` is the stored row width (the packed width for int4 / binary
+    codes), padded to whole 128-lane tiles as VMEM holds it; ``planes`` is
+    the number of f32 feature planes the kernel unpacks each row into
+    (``quantized.PLANES``).
+    """
+    dl = ceil_to(d, LANE)
+    return (bq * bn * dl * (itemsize + 4 * planes) + bq * dl * 4 * planes
+            + 3 * bq * (k + bn) * 4)
 
 
 def vmem_swap(bg: int, g: int, k: int) -> int:

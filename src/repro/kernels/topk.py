@@ -9,16 +9,22 @@ uses for the softmax, applied to k-selection:
   grid = (q/bq, n/bn)        # db axis sequential ("arbitrary")
   state: o_dists[bq, k], o_ids[bq, k] live in the *output* refs, revisited
   per step:   d = dist(q_tile, db_tile)          # MXU (gram) or VPU form
-              merge top-k of concat([state, d])  # one lax.top_k per tile
+              state = merge_topk(state, d)       # k rounds of extract-min
 
 HBM traffic drops from ``4qn`` bytes (write + read the matrix, then select) to
 ``~(q + n) d`` input bytes + ``8qk`` output bytes — for the recsys
 ``retrieval_cand`` cell (1 query x 1M candidates) that's the difference
 between memory-bound and compute-bound (see EXPERIMENTS.md §Perf).
 
-The merge uses ``jax.lax.top_k`` over ``[bq, k + bn]``; ids travel with the
-distances. Padded database rows are masked to ``BIG`` via their global column
-index, so callers may pad freely.
+The merge (:func:`merge_topk`, shared by every fused top-k kernel) keeps the
+``[bq, k]`` state and the ``[bq, bn]`` tile as two pieces and runs ``k`` rounds
+of extract-min over both: lane ``min``, first index of that min by ``iota`` +
+``where`` + ``min``, then the taken slot is masked to ``inf``. Mosaic lowers
+every one of those ops; it lowers neither ``top_k`` nor a per-row lane
+gather, nor a lane-unaligned ``concatenate``. Ties go to the
+state first and then to the lower tile column — the order ``top_k`` gives
+over ``concat([state, tile])``. Padded database rows are masked to ``BIG`` via
+their global column index, so callers may pad freely.
 """
 
 from __future__ import annotations
@@ -30,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import tiling
-from repro.kernels.ref import BIG, FORMS, GRAM_FORMS, NORM_FORMS
+from repro.kernels.ref import BIG, FORMS, GRAM_FORMS, NORM_FORMS, PRECISION
 
 Array = jax.Array
 
@@ -46,7 +52,8 @@ def _tile_distance(form: str, q: Array, db: Array) -> Array:
     q = q.astype(jnp.float32)
     db = db.astype(jnp.float32)
     if form in GRAM_FORMS:
-        g = jnp.dot(q, db.T, preferred_element_type=jnp.float32)
+        g = jnp.dot(q, db.T, preferred_element_type=jnp.float32,
+                    precision=PRECISION)
         if form == "dot":
             return -g
         qq = jnp.sum(q * q, axis=1, keepdims=True)
@@ -64,6 +71,43 @@ def _tile_distance(form: str, q: Array, db: Array) -> Array:
     raise ValueError(form)
 
 
+def merge_topk(best_d: Array, best_i: Array, tile_d: Array, base) -> tuple:
+    """Merge a ``[bq, bn]`` distance tile into a running ``[bq, k]`` top-k.
+
+    ``best_d`` / ``best_i``: the running state, ascending; ``tile_d``: the
+    new tile, whose column ``c`` carries id ``base + c``. Returns the new
+    ``(dists, ids)`` state: the ``k`` smallest of state and tile, ascending,
+    ties to the state and then to the lower column (``top_k``'s order
+    over ``concat([state, tile])``). Built from lane reductions, ``iota``
+    and ``where`` only, so it lowers on Mosaic inside a kernel body.
+    """
+    bq, k = best_d.shape
+    bn = tile_d.shape[1]
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    lane_n = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+
+    def take_one(r, carry):
+        sd, td, od, oi = carry
+        ms = jnp.min(sd, axis=1, keepdims=True)  # [bq, 1]
+        mt = jnp.min(td, axis=1, keepdims=True)
+        from_state = ms <= mt
+        ps = jnp.min(jnp.where(sd == ms, lane_k, k), axis=1, keepdims=True)
+        pt = jnp.min(jnp.where(td == mt, lane_n, bn), axis=1, keepdims=True)
+        hit_s = lane_k == ps
+        id_s = jnp.sum(jnp.where(hit_s, best_i, 0), axis=1, keepdims=True)
+        sd = jnp.where(hit_s & from_state, jnp.inf, sd)
+        td = jnp.where((lane_n == pt) & ~from_state, jnp.inf, td)
+        out = lane_k == r
+        od = jnp.where(out, jnp.minimum(ms, mt), od)
+        oi = jnp.where(out, jnp.where(from_state, id_s, base + pt), oi)
+        return sd, td, od, oi
+
+    _, _, od, oi = jax.lax.fori_loop(
+        0, k, take_one, (best_d, tile_d, best_d, best_i)
+    )
+    return od, oi
+
+
 def _knn_kernel(q_ref, db_ref, od_ref, oi_ref, *, form, k, bn, n_valid):
     j = pl.program_id(1)
 
@@ -77,11 +121,7 @@ def _knn_kernel(q_ref, db_ref, od_ref, oi_ref, *, form, k, bn, n_valid):
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
     d = jnp.where(col < n_valid, d, BIG)
 
-    all_d = jnp.concatenate([od_ref[...], d], axis=1)  # [bq, k + bn]
-    all_i = jnp.concatenate([oi_ref[...], col], axis=1)
-    neg, idx = jax.lax.top_k(-all_d, k)
-    od_ref[...] = -neg
-    oi_ref[...] = jnp.take_along_axis(all_i, idx, axis=1)
+    od_ref[...], oi_ref[...] = merge_topk(od_ref[...], oi_ref[...], d, j * bn)
 
 
 @functools.partial(
@@ -154,35 +194,52 @@ def knn_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _rank_tile_distance(form: str, q: Array, c: Array, cc) -> Array:
-    """[bq, d] x [bq, bn, d] -> [bq, bn] per-query distance tile.
+def _rank_tile_distance(form: str, qs, cs, cc=None) -> Array:
+    """Per-query distance tile ``[bq, bn]`` from matching feature planes.
 
-    Every query row sees its *own* candidate rows (the beam-search layout),
-    so there is no shared [bq, d] x [d, bn] matmul form; the reduction over
-    ``d`` runs on the VPU against the VMEM-resident candidate block, mirroring
-    ``pairwise._vpu_kernel``. Norm-consuming forms receive the gathered
-    ``||c||^2`` tile (``cc``) from the index-side cache instead of re-reducing
-    the candidate cube.
+    ``qs``: ``[bq, dp]`` query planes; ``cs``: the matching ``[bq, bn, dp]``
+    candidate planes. Dense rows are one plane holding all of ``d``; packed
+    codes (``quantized.py``) unpack into several planes that partition the
+    dimensions, so no interleaving reshape ever runs in VMEM. Every query
+    row sees its *own* candidate rows (the beam-search layout), so there is
+    no shared [bq, d] x [d, bn] matmul form; the reduction over ``d`` runs
+    on the VPU against the VMEM-resident candidate block, mirroring
+    ``pairwise._vpu_kernel``. Norm-consuming forms take the gathered
+    ``||c||^2`` tile (``cc``) from the index-side cache when given, and
+    reduce it from the planes otherwise.
     """
-    q = q.astype(jnp.float32)
-    c = c.astype(jnp.float32)
-    if form in GRAM_FORMS:
-        g = jnp.sum(q[:, None, :] * c, axis=-1)  # [bq, bn]
-        if form == "dot":
-            return -g
-        qq = jnp.sum(q * q, axis=-1)[:, None]
-        cc = cc.astype(jnp.float32)
-        if form in ("sqeuclidean", "l2"):
-            d2 = jnp.maximum(qq + cc - 2.0 * g, 0.0)
-            return d2 if form == "sqeuclidean" else jnp.sqrt(d2)
-        norm = jnp.sqrt(jnp.maximum(qq, _EPS)) * jnp.sqrt(jnp.maximum(cc, _EPS))
-        return 1.0 - jnp.clip(g / norm, -1.0, 1.0)
-    diff = jnp.abs(q[:, None, :] - c)
-    if form == "l1":
-        return jnp.sum(diff, axis=-1)
-    if form == "chebyshev":
-        return jnp.max(diff, axis=-1)
-    raise ValueError(form)
+    # Per-plane partial terms, combined across planes: Gram forms carry
+    # (q.c, |q|^2, |c|^2), the VPU forms their one reduction.
+    combine = jnp.maximum if form == "chebyshev" else jnp.add
+    acc = None
+    for q, c in zip(qs, cs):
+        q = q.astype(jnp.float32)
+        c = c.astype(jnp.float32)
+        if form in GRAM_FORMS:
+            part = (
+                jnp.sum(q[:, None, :] * c, axis=-1),  # [bq, bn]
+                jnp.sum(q * q, axis=-1)[:, None],
+                jnp.sum(c * c, axis=-1)
+                if cc is None and form in NORM_FORMS else 0.0,
+            )
+        elif form in ("l1", "chebyshev"):
+            diff = jnp.abs(q[:, None, :] - c)
+            part = (jnp.sum(diff, axis=-1) if form == "l1"
+                    else jnp.max(diff, axis=-1),)
+        else:
+            raise ValueError(form)
+        acc = part if acc is None else tuple(map(combine, acc, part))
+    if form not in GRAM_FORMS:
+        return acc[0]
+    g, qq, cc_planes = acc
+    if form == "dot":
+        return -g
+    cc = cc_planes if cc is None else cc.astype(jnp.float32)
+    if form in ("sqeuclidean", "l2"):
+        d2 = jnp.maximum(qq + cc - 2.0 * g, 0.0)
+        return d2 if form == "sqeuclidean" else jnp.sqrt(d2)
+    norm = jnp.sqrt(jnp.maximum(qq, _EPS)) * jnp.sqrt(jnp.maximum(cc, _EPS))
+    return 1.0 - jnp.clip(g / norm, -1.0, 1.0)
 
 
 def _rank_kernel(q_ref, c_ref, ok_ref, *rest, form, k, bn):
@@ -201,16 +258,11 @@ def _rank_kernel(q_ref, c_ref, ok_ref, *rest, form, k, bn):
         oi_ref[...] = jnp.full_like(oi_ref, -1)
 
     cc = cc_ref[...] if cc_ref is not None else None
-    d = _rank_tile_distance(form, q_ref[...], c_ref[...], cc)  # [bq, bn]
-    d = jnp.where(ok_ref[...] != 0, d, BIG)
-    bq = d.shape[0]
-    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
-
-    all_d = jnp.concatenate([od_ref[...], d], axis=1)  # [bq, k + bn]
-    all_i = jnp.concatenate([oi_ref[...], col], axis=1)
-    neg, idx = jax.lax.top_k(-all_d, k)
-    od_ref[...] = -neg
-    oi_ref[...] = jnp.take_along_axis(all_i, idx, axis=1)
+    d = _rank_tile_distance(form, [q_ref[...]], [c_ref[...]], cc)
+    # widen the int8 mask first: Mosaic cannot relayout an int8 compare
+    # onto the reduced distance tile ("Lane broadcast")
+    d = jnp.where(ok_ref[...].astype(jnp.int32) != 0, d, BIG)
+    od_ref[...], oi_ref[...] = merge_topk(od_ref[...], oi_ref[...], d, j * bn)
 
 
 @functools.partial(
@@ -296,5 +348,5 @@ def rank_pallas(
     )(*in_arrays)
     # Honour the slot contract (in [0, w)) even for masked/short rows: the
     # -1 init and padded columns rank as BIG but must not leak out-of-range
-    # indices to host-side consumers (np.take_along_axis would wrap them).
+    # indices to host-side consumers (a NumPy per-row gather would wrap them).
     return dists[:b], jnp.clip(slots[:b], 0, w - 1)

@@ -161,15 +161,13 @@ def _memory_dict(compiled) -> dict:
 def _compile_cell(cell, mesh):
     import jax
 
-    from repro.launch.mesh import set_mesh
-
     jitted = jax.jit(
         cell.step,
         in_shardings=cell.in_shardings(mesh),
         out_shardings=cell.out_shardings(mesh),
         donate_argnums=cell.donate,
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*cell.args)
         compiled = lowered.compile()
     return compiled

@@ -13,19 +13,11 @@ Axes:
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-# jax >= 0.6 exposes explicit axis types; on older jax every mesh axis is
-# implicitly Auto, so the kwarg is simply omitted.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
 
-
-def _axis_types_kw(n_axes: int) -> dict:
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,30 +28,19 @@ def make_production_mesh(*, multi_pod: bool = False):
         n *= s
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
     # The dry-run process holds 512 placeholder devices; the single-pod mesh
     # uses the first 256.
     from jax.experimental import mesh_utils
 
     dm = mesh_utils.create_device_mesh(shape, devices=devs[:n])
-    return jax.sharding.Mesh(dm, axes, **_axis_types_kw(len(axes)))
+    return jax.sharding.Mesh(dm, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small-device runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_types_kw(len(axes)))
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where available (jax >= 0.6); on older jax nothing needs
-    installing (shard_map receives the mesh explicitly), so this is a no-op
-    context.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext(mesh)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=_auto(len(axes)))
 
 
 def batch_axes_of(mesh) -> tuple:

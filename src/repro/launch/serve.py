@@ -20,9 +20,10 @@ instead (DESIGN.md §3.10): N replicas behind the retry/hedge/backoff
 ``Router``, writes fanned out through the shared write log. ``--faults``
 takes a deterministic fault plan (``kind:rR@START+DURATION[:DELAY]``,
 ``;``-separated — e.g. ``"wedge:r1@20+8;error:r2@40+5"``) injected into the
-replica batch handlers; the run reports caller-visible errors (expected:
-zero), retries, hedges and the health event log alongside the latency
-percentiles.
+replica batch handlers; the run reports caller-visible errors, retries,
+hedges and the health event log alongside the latency percentiles, and
+exits non-zero if any caller saw an error (a fault plan must be routed
+around).
 
 Quality & SLO observability (DESIGN.md §3.12): ``--shadow-sample N``
 re-answers 1 served query in N exactly on a background worker and prints
@@ -47,12 +48,13 @@ from repro import obs
 from repro.core.index import PDASCIndex
 from repro.data import make_dataset
 from repro.kernels.ops import KernelConfig, knn
+from repro.launch import compile_cache
 from repro.online import EpochHandle, live_dataset
 from repro.query import Query
 from repro.serving import BatchingEngine, QueryHandler
 
 
-def _parse():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--dataset", default="dense_embed")
     p.add_argument("--n", type=int, default=20000)
@@ -154,11 +156,12 @@ def _parse():
     p.add_argument("--bd", type=int, default=kd.bd)
     p.add_argument("--bq", type=int, default=kd.bq)
     p.add_argument("--row-chunk", type=int, default=kd.row_chunk)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def _serve_replicated(args, idx, kernel, train, test):
-    """The --replicas path: N replicas behind the fault-tolerant router."""
+def _serve_replicated(args, idx, kernel, train, test) -> int:
+    """The --replicas path: N replicas behind the fault-tolerant router.
+    Returns the number of caller-visible errors."""
     from repro.query import degraded
     from repro.serving import FaultPlan, ReplicaSet, Router, RouterConfig
 
@@ -234,7 +237,8 @@ def _serve_replicated(args, idx, kernel, train, test):
               f"to {args.trace_dump}")
     router.close(close_replicas=True)
 
-    lat_ms = np.array(lat) * 1e3
+    # every query may have failed: no latencies to take percentiles of
+    lat_ms = np.array(lat or [np.nan]) * 1e3
     counts = router.event_counts()
     print(f"[serve] {args.queries} queries over {args.replicas} replicas: "
           f"errors={errors} p50={np.percentile(lat_ms, 50):.1f}ms "
@@ -261,10 +265,12 @@ def _serve_replicated(args, idx, kernel, train, test):
             print(f"[serve] slowest sampled trace "
                   f"({len(router.traces)} retained):")
             print(ex.render())
+    return errors
 
 
-def main():
-    args = _parse()
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
     # Periodic metrics dumper (DESIGN.md §3.11): rewrites PATH whole every
     # few seconds while serving; closed (with a final snapshot) at exit.
     dumper = None
@@ -308,14 +314,30 @@ def main():
     kernel = KernelConfig(bm=args.bm, bn=args.bn, bd=args.bd, bq=args.bq,
                           row_chunk=args.row_chunk)
 
-    if args.replicas > 1:
-        try:
-            _serve_replicated(args, idx, kernel, train, test)
-        finally:
-            if dumper is not None:
-                dumper.close()
-        return
+    try:
+        if args.replicas > 1:
+            errors = _serve_replicated(args, idx, kernel, train, test)
+            if errors:
+                # a fault plan is routed around: any error a caller saw is
+                # a failed run, not a statistic
+                raise SystemExit(f"[serve] {errors} caller-visible errors")
+        else:
+            serve_engine(args, idx, kernel, train, test)
+    finally:
+        if dumper is not None:
+            dumper.close()
 
+
+def serve_engine(args, idx, kernel, train, test) -> dict:
+    """The single-engine path: ``Query`` -> ``QueryHandler`` ->
+    ``BatchingEngine``, ``args.queries`` requests drawn from ``test``, then
+    recall against exact kNN (``ops.knn``) over ``train``.
+
+    ``args`` is a :func:`parse_args` namespace. Returns ``recall``,
+    ``p50_ms`` / ``p99_ms``, ``warmup_s`` (first request, compile
+    included), ``mean_batch_occupancy``, the served rows ``q_rows`` and
+    per-query ``dists`` / ``ids`` / exact ``gt`` ids as ``[Q, k]`` arrays.
+    """
     handle = None
     if args.churn > 0:
         idx.enable_mutations(delta_capacity=args.delta_capacity)
@@ -363,7 +385,9 @@ def main():
         write_handler=handle.apply_writes if handle is not None else None,
     )
     # warmup compile
-    engine.submit(test[0]).wait(timeout=120)
+    t0 = time.time()
+    engine.submit(test[0]).wait(timeout=900)
+    warmup_s = time.time() - t0
 
     # Deterministic 1-in-N tracing on the single-engine path: the Trace is
     # created at submit time (there is no router in front), the engine
@@ -391,7 +415,7 @@ def main():
               f"(one write per query slot ahead of the scored tail)")
     write_every = (head // churn) if churn else 0
     upserted_ids: list[int] = []
-    lat, results = [], []
+    lat, results, result_dists = [], [], []
     for j, i in enumerate(q_rows):
         if (write_every and j < head and j % write_every == 0
                 and j // write_every < churn):
@@ -411,9 +435,10 @@ def main():
         tr = sampler.sample("request", j, kind="search")
         t0 = time.time()
         req = engine.submit(test[i], span=tr.root if tr else None)
-        _, ids = req.wait(timeout=60)
+        dists, ids = req.wait(timeout=60)
         lat.append(time.time() - t0)
         results.append(ids)
+        result_dists.append(dists)
         if est is not None and est.should_sample(j):
             est.observe(j, test[i], ids,
                         pipeline=handler.describe()["effective_pipeline"])
@@ -477,8 +502,17 @@ def main():
             f.write(sampler.buffer.to_json(indent=1))
         print(f"[serve] wrote {len(sampler.buffer)} traces "
               f"to {args.trace_dump}")
-    if dumper is not None:
-        dumper.close()
+    return dict(
+        recall=float(rec),
+        p50_ms=float(np.percentile(lat, 50)),
+        p99_ms=float(np.percentile(lat, 99)),
+        warmup_s=warmup_s,
+        mean_batch_occupancy=engine.mean_occupancy,
+        q_rows=q_rows,
+        ids=np.stack([np.asarray(r) for r in results]),
+        dists=np.stack([np.asarray(r) for r in result_dists]),
+        gt=gt,
+    )
 
 
 if __name__ == "__main__":

@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.data import lm_tokens, recsys_batch
-from repro.launch.mesh import batch_axes_of, make_mesh, set_mesh
+from repro.launch.mesh import batch_axes_of, make_mesh
 from repro.models import recsys as rec_lib
 from repro.models import transformer as tfm
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -102,7 +102,7 @@ def main():
 
     tl_cfg = TrainLoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt,
                              ckpt_every=args.ckpt_every)
-    ctx = set_mesh(mesh) if mesh is not None else _null()
+    ctx = jax.set_mesh(mesh) if mesh is not None else _null()
     with ctx:
         params, opt_state, hist = train_loop(
             step_fn, params, opt_state, make_batch_hb, tl_cfg, log_fn=log_fn

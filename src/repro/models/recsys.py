@@ -338,7 +338,7 @@ def retrieval_step(params, batch, candidates, cfg: RecsysConfig, mesh=None,
         top, idx = jax.lax.top_k(scores, k)
         return top, idx.astype(jnp.int32)
 
-    from repro.core.distributed import axis_size, shard_map, topk_merge
+    from repro.core.distributed import topk_merge
 
     n = candidates.shape[0]
     Pn = 1
@@ -349,14 +349,14 @@ def retrieval_step(params, batch, candidates, cfg: RecsysConfig, mesh=None,
     def body(u_rep, cand_local):
         shard = jnp.int32(0)
         for a in cand_axes:
-            shard = shard * axis_size(a) + jax.lax.axis_index(a)
+            shard = shard * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         scores = u_rep @ cand_local[0].T  # [B, per]
         top, idx = jax.lax.top_k(scores, k)
         gids = idx.astype(jnp.int32) + shard * jnp.int32(per)
         return topk_merge(-top, gids, tuple(cand_axes), k)  # ascending -score
 
-    fn = shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(), P(tuple(cand_axes), None, None)),
         out_specs=(P(), P()),
     )

@@ -441,13 +441,12 @@ def moe_block(x: Array, lw: dict, cfg: TransformerConfig, sh: ShardingConfig,
     if mesh is None:  # single-device smoke path
         return body(x, lw["router"], lw["we_gate"], lw["we_up"], lw["we_down"])
 
-    from repro.core.distributed import shard_map  # check_vma=False wrapper
-
     b, m = sh.b, sh.m
     x_spec = P(b, m, None) if shard_tokens else P(b, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(
             x_spec,  # tokens sharded over batch axes (+ model when possible)
             P(None, None),  # router: replicated
@@ -477,8 +476,6 @@ def moe_decode_2d(x: Array, lw: dict, cfg: TransformerConfig,
 
     x: [B, d] sharded over ``sh.cache_batch_axes``; returns same.
     """
-    from repro.core.distributed import shard_map
-
     moe = cfg.moe
     B, d = x.shape
     E, k = moe.n_experts, moe.top_k
@@ -559,8 +556,8 @@ def moe_decode_2d(x: Array, lw: dict, cfg: TransformerConfig,
 
     cb_spec = tuple(cb) if cb else None
     fs_spec = tuple(fs) if fs else None
-    fn = shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(
             P(cb_spec, None),
             P(None, None),

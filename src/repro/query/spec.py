@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Union
 
+import jax
 import numpy as np
 
 from repro.core import distances as dist_lib
@@ -132,11 +133,7 @@ def degraded(query: Query) -> Query:
 def is_concrete(Q) -> bool:
     """False inside a jit/shard_map trace (validation must be skipped there:
     a plan may be executed inside a lowered step, e.g. the dry-run cells)."""
-    try:
-        from jax.core import Tracer
-    except ImportError:  # pragma: no cover - future jax relocations
-        return True
-    return not isinstance(Q, Tracer)
+    return not isinstance(Q, jax.core.Tracer)
 
 
 def validate_query_batch(

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distributed import shard_map
 from repro.optim import adamw as opt_lib
 from repro.optim import compression as comp
 
@@ -95,8 +94,8 @@ def make_compressed_dp_step(
             lambda x: P((pod_axis, data_axis), *([None] * (x.ndim - 1))), batch
         )
         rep_tree = lambda t: jax.tree.map(lambda _: rep, t)
-        fn = shard_map(
-            body, mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(rep_tree(params), rep_tree(opt_state),
                       rep_tree(comp_state), batch_specs),
             out_specs=(rep_tree(params), rep_tree(opt_state),
@@ -105,4 +104,5 @@ def make_compressed_dp_step(
         )
         return fn(params, opt_state, comp_state, batch)
 
-    return step, init_comp_state
+    # jitted: run eagerly, the shard_map re-traces its body on every step
+    return jax.jit(step), init_comp_state

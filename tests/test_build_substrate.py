@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _jaxpr_walk import max_outvar_elems, pallas_bodies
 
 from repro.core import distances as dl
 from repro.core import kmedoids as km
@@ -138,34 +139,6 @@ def test_swap_deltas_kernel_vmapped_parity():
 # ---------------------------------------------------------------------------
 
 
-def _max_outvar_elems(jaxpr, into_params=True):
-    seen = [0]
-
-    def scan(jx):
-        for eqn in jx.eqns:
-            for v in eqn.outvars:
-                aval = getattr(v, "aval", None)
-                if aval is not None and hasattr(aval, "shape"):
-                    elems = 1
-                    for s in aval.shape:
-                        elems *= int(s)
-                    seen[0] = max(seen[0], elems)
-            if not into_params:
-                continue
-            for val in eqn.params.values():
-                if isinstance(val, jax.core.ClosedJaxpr):
-                    scan(val.jaxpr)
-                elif isinstance(val, jax.core.Jaxpr):
-                    scan(val)
-                elif isinstance(val, (tuple, list)):
-                    for x in val:
-                        if isinstance(x, jax.core.ClosedJaxpr):
-                            scan(x.jaxpr)
-
-    scan(jaxpr)
-    return seen[0]
-
-
 def test_chunked_build_never_materialises_all_group_matrices():
     """With group_chunk streaming, no intermediate of the traced MSA build
     reaches [G, g, g] elements: the clustering working set is bounded by
@@ -178,7 +151,7 @@ def test_chunked_build_never_materialises_all_group_matrices():
             x, gl=gl, distance="euclidean", method="pam", group_chunk=gc
         )
     )(data)
-    seen = _max_outvar_elems(closed.jaxpr)
+    seen = max_outvar_elems(closed.jaxpr)
     assert seen < G * gl * gl, (seen, G * gl * gl)
     assert seen <= gc * gl * gl, (seen, gc * gl * gl)
 
@@ -198,28 +171,14 @@ def test_sweep_kernel_streams_row_tiles():
         lambda *a: ops.swap_deltas(*a, k=k, bg=bg, force_pallas=True)
     )(D, d1, d2, n1, valid)
 
-    # Find the pallas_call eqn and scan only its kernel-body jaxpr.
-    bodies = []
-
-    def find(jx):
-        for eqn in jx.eqns:
-            if "pallas" in eqn.primitive.name:
-                for val in eqn.params.values():
-                    if isinstance(val, jax.core.ClosedJaxpr):
-                        bodies.append(val.jaxpr)
-                    elif isinstance(val, jax.core.Jaxpr):
-                        bodies.append(val)
-            for val in eqn.params.values():
-                if isinstance(val, jax.core.ClosedJaxpr):
-                    find(val.jaxpr)
-
-    find(closed.jaxpr)
+    # Scan only the pallas_call's kernel-body jaxpr.
+    bodies = pallas_bodies(closed.jaxpr)
     assert bodies, "no pallas_call in the traced sweep"
     gc_pad = -(-g // 128) * 128
     kp = -(-k // 8) * 8
     tile_bound = max(bg, kp) * gc_pad
     for body in bodies:
-        seen = _max_outvar_elems(body)
+        seen = max_outvar_elems(body)
         assert seen <= tile_bound < g * g, (seen, tile_bound, g * g)
 
 

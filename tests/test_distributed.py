@@ -89,7 +89,7 @@ print("PERM_OK")
 def test_compressed_dp_step_runs_and_learns():
     out = run_in_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig, adamw_init
 from repro.train.dp_step import make_compressed_dp_step
 
@@ -109,7 +109,7 @@ step, init_cs = make_compressed_dp_step(
     compress_ratio=0.25)
 cs = init_cs(params)
 losses = []
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for s in range(60):
         x = rng.normal(size=(64, 16)).astype(np.float32)
         y = x @ W_true

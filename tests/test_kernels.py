@@ -112,3 +112,33 @@ def test_resolve_form():
     assert ops.resolve_form(dl.get("manhattan")) == "l1"
     assert ops.resolve_form("sqeuclidean") == "sqeuclidean"
     assert ops.resolve_form("haversine") is None
+
+
+@pytest.mark.parametrize("k,bn", [(1, 8), (4, 16), (10, 512), (16, 16)])
+def test_merge_topk_matches_top_k_over_concat(k, bn):
+    """The in-kernel extract-min merge equals ``lax.top_k`` over
+    ``concat([state, tile])`` exactly — ids included, ties and the BIG /
+    -1 initial state included (integer-valued distances force ties)."""
+    import jax
+
+    from repro.kernels.ref import BIG
+    from repro.kernels.topk import merge_topk
+
+    rng = np.random.default_rng(k * 100 + bn)
+    bq, base = 8, 1000
+    # running state: ascending, some slots still at the BIG / -1 init
+    state_d = np.sort(rng.integers(0, 6, (bq, k)).astype(np.float32), axis=1)
+    state_d[:, k // 2:] = BIG
+    state_i = rng.integers(0, base, (bq, k)).astype(np.int32)
+    state_i[:, k // 2:] = -1
+    tile = rng.integers(0, 6, (bq, bn)).astype(np.float32)
+    tile[:, ::3] = BIG  # masked candidates tie with the BIG state
+    got_d, got_i = merge_topk(jnp.asarray(state_d), jnp.asarray(state_i),
+                              jnp.asarray(tile), base)
+    all_d = np.concatenate([state_d, tile], axis=1)
+    all_i = np.concatenate(
+        [state_i, np.broadcast_to(base + np.arange(bn), (bq, bn))], axis=1)
+    neg, idx = jax.lax.top_k(-jnp.asarray(all_d), k)
+    np.testing.assert_array_equal(np.asarray(got_d), -np.asarray(neg))
+    np.testing.assert_array_equal(np.asarray(got_i),
+                                  np.take_along_axis(all_i, np.asarray(idx), 1))

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _jaxpr_walk import max_outvar_elems
 
 from repro.core import distances as dl
 from repro.core import msa, nsa, radius as rl
@@ -232,25 +233,5 @@ def test_dense_l1_never_materialises_cube():
     )(jnp.zeros((B, d), jnp.float32))
 
     cube = B * n0 * d
-    seen = [0]
-
-    def scan(jaxpr):
-        for eqn in jaxpr.eqns:
-            for v in eqn.outvars:
-                aval = getattr(v, "aval", None)
-                if aval is not None and hasattr(aval, "shape"):
-                    elems = 1
-                    for s in aval.shape:
-                        elems *= int(s)
-                    seen[0] = max(seen[0], elems)
-            for val in eqn.params.values():
-                if isinstance(val, jax.core.ClosedJaxpr):
-                    scan(val.jaxpr)
-                elif isinstance(val, jax.core.Jaxpr):
-                    scan(val)
-                elif isinstance(val, (tuple, list)):
-                    for x in val:
-                        if isinstance(x, jax.core.ClosedJaxpr):
-                            scan(x.jaxpr)
-    scan(closed.jaxpr)
-    assert seen[0] < cube, (seen[0], cube)
+    seen = max_outvar_elems(closed.jaxpr)
+    assert seen < cube, (seen, cube)

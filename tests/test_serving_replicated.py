@@ -437,3 +437,22 @@ def test_long_faulted_schedule_zero_caller_errors(built):
         assert counted == len(transitions)
     finally:
         router.close(close_replicas=True)
+
+
+def test_serve_cli_exits_nonzero_on_caller_errors(monkeypatch, tmp_path):
+    """The --replicas CLI path fails the run when any caller saw an error:
+    every replica erroring leaves nothing to route around."""
+    from repro.launch import serve
+
+    # a cache directory given by the environment: main() then changes no
+    # process-wide JAX setting for the tests that follow
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+    with pytest.raises(SystemExit) as exc:
+        serve.main([
+            "--n", "600", "--gl", "32", "--queries", "4", "--batch", "4",
+            "--mode", "beam", "--beam", "8", "--replicas", "2",
+            "--deadline-ms", "20000",
+            "--faults", "error:r0@1+1000;error:r1@1+1000",
+        ])
+    assert "caller-visible errors" in str(exc.value.code)

@@ -1,0 +1,104 @@
+"""Compile the Pallas kernels for a TPU v5e without the chip.
+
+The TPU compiler is installed with JAX and compiles for a described,
+unattached ``v5e:2x2`` topology. What it refuses here (ops Mosaic cannot
+lower, blocks off the (8, 128) tiling, VMEM overflow) would fail the first
+call on the chip; interpret-mode parity tests cannot see any of it. Shapes
+are the PDASC serving / build widths: d = 100 (GLOVE), 2^20 database rows,
+beam candidates w = 1024, group length g = 1024 with 512 medoids.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and every test worker imports every
+test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import kmedoids, pairwise, quantized, topk
+from repro.kernels.ref import packed_width
+
+D = 100
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep these compiles out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("form,dtype", [
+    ("l2", jnp.float32), ("cosine", jnp.float32), ("l1", jnp.float32),
+    ("l2", jnp.bfloat16),
+])
+def test_pairwise_compiles(one_chip, form, dtype):
+    x = jax.ShapeDtypeStruct((1024, D), dtype, sharding=one_chip)
+    _compile(lambda a, b: pairwise.pairwise_pallas(a, b, form=form), x, x)
+
+
+def test_knn_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((256, D), jnp.float32, sharding=one_chip)
+    db = jax.ShapeDtypeStruct((1 << 20, D), jnp.float32, sharding=one_chip)
+    _compile(lambda a, b: topk.knn_pallas(a, b, form="l2", k=10), q, db)
+
+
+@pytest.mark.parametrize("form", ["l2", "dot", "l1"])
+def test_rank_compiles(one_chip, form):
+    b, w = 32, 1024
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    _compile(
+        lambda q, c, ok, cc: topk.rank_pallas(q, c, ok, cc, form=form, k=32),
+        S((b, D)), S((b, w, D)), S((b, w), jnp.bool_), S((b, w)),
+    )
+
+
+@pytest.mark.parametrize("fmt,dtype", [
+    ("dense", jnp.int8), ("dense", jnp.float16), ("int4", jnp.int8),
+    ("binary", jnp.uint8),
+])
+def test_scan_compiles(one_chip, fmt, dtype):
+    b, w = 32, 1024
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    _compile(
+        lambda q, c, s, ok: quantized.scan_pallas(q, c, s, ok, form="l2",
+                                                  k=128, fmt=fmt),
+        S((b, D)), S((b, w, packed_width(D, fmt)), dtype), S((b, w)),
+        S((b, w), jnp.bool_),
+    )
+
+
+def test_swap_deltas_compiles(one_chip):
+    g, k = 1024, 512
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    _compile(
+        lambda D_, d1, d2, n1, v: kmedoids.swap_deltas_pallas(
+            D_, d1, d2, n1, v, k=k),
+        S((g, g)), S((g,)), S((g,)), S((g,), jnp.int32), S((g,), jnp.bool_),
+    )
