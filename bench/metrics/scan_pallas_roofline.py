@@ -1,0 +1,7 @@
+"""``scan_pallas`` time in the trace against its roofline (``bench/kernels``)."""
+
+from annbench import roofline
+
+
+def read(ctx):
+    return roofline.share(ctx, "scan_pallas")
