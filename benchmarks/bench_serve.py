@@ -17,12 +17,11 @@ instrumentation overhead (``--smoke`` asserts non-zero engine/router/store
 series, a complete exemplar trace, and overhead ratio >= 0.95).
 
 A fourth ``quality`` scenario (DESIGN.md §3.12) adds shadow recall
-sampling, the plan-cost JSONL log and SLO burn alerts on the same tier,
-asserting: online recall within +-0.05 of the offline recall over the
-same served queries; a non-empty re-loadable cost log; >= 1 SLO burn
-alert under an injected wedge and zero fault-free; and instrumented +
-shadow-sampled throughput >= 0.93x uninstrumented. The run always leaves
-``experiments/serve_metrics.json`` behind for
+sampling and SLO burn alerts on the same tier, asserting: online recall
+within +-0.05 of the offline recall over the same served queries; >= 1
+SLO burn alert under an injected wedge and zero fault-free; and
+instrumented + shadow-sampled throughput >= 0.93x uninstrumented. The
+run always leaves ``experiments/serve_metrics.json`` behind for
 ``python -m repro.obs.report`` (the CI offline-report contract).
 
 Scenarios: ``fault_free``, and ``wedged`` — a deterministic ``FaultPlan``
@@ -328,17 +327,13 @@ def telemetry(smoke: bool = False, seed: int = 0):
         router.close(close_replicas=True)
 
 
-def quality(smoke: bool = False, seed: int = 0,
-            costlog_path: str = "experiments/serve_costlog.jsonl"):
+def quality(smoke: bool = False, seed: int = 0):
     """Quality & SLO scenario (DESIGN.md §3.12): a store-backed two_stage
-    tier with shadow recall sampling, a plan-cost log on the traced
-    requests, and an SLO tracker with multi-rate burn alerts. The four
-    acceptance bars (smoke and full):
+    tier with shadow recall sampling and an SLO tracker with multi-rate
+    burn alerts. The three acceptance bars (smoke and full):
 
       * the online (shadow-sampled) recall estimate lands within +-0.05 of
         the offline recall computed over the same served queries,
-      * the cost log is non-empty and loads back with the documented
-        schema (v/seq/latency_s/spans + plan features),
       * the SLO tracker fires >= 1 burn alert under an injected wedge and
         ZERO on the fault-free leg,
       * instrumented + shadow-sampled throughput stays >= 0.93x the
@@ -361,15 +356,8 @@ def quality(smoke: bool = False, seed: int = 0,
     query = Query(k=k, execution="two_stage", beam=32, rerank_width=64,
                   with_stats=False)
     rs = ReplicaSet(idx, query, n_replicas=2, batch_size=8, max_wait_ms=1.0)
-    os.makedirs(os.path.dirname(costlog_path) or ".", exist_ok=True)
-    if os.path.exists(costlog_path):
-        os.remove(costlog_path)
-    from repro.obs import costlog as costlog_lib
-
-    costlog = obs.CostLog(costlog_path)
     router = Router(rs, RouterConfig(deadline_s=30.0, seed=seed,
-                                     trace_every=4, shadow_every=4),
-                    costlog=costlog)
+                                     trace_every=4, shadow_every=4))
     est = router.quality
     try:
         warm = [r.submit(test[0]) for r in rs.replicas]
@@ -381,7 +369,7 @@ def quality(smoke: bool = False, seed: int = 0,
             router.search(test[i])
         assert est.drain(timeout=120), "quality: shadow warmup never drained"
 
-        # -- (d) overhead guard: uninstrumented vs instrumented+shadowed --
+        # -- (c) overhead guard: uninstrumented vs instrumented+shadowed --
         every_n, router._sampler.every_n = router._sampler.every_n, 0
         qps_off, qps_on = [], []
         for t in range(trials):
@@ -424,11 +412,7 @@ def quality(smoke: bool = False, seed: int = 0,
             for j, (_, served) in enumerate(rows_served)
         ]))
 
-        # -- (b) the cost log loads back with the documented schema -------
-        costlog.close()
-        recs = costlog_lib.load(costlog_path)
-
-        # -- (c) SLO: zero alerts fault-free, >= 1 under a wedge ----------
+        # -- (b) SLO: zero alerts fault-free, >= 1 under a wedge ----------
         p99_s = float(np.percentile(np.array(lats), 99))
         target_s = max(5.0 * p99_s, 0.25)
         spec = obs.SLOSpec(latency_p99_s=target_s, window_s=8.0,
@@ -476,8 +460,6 @@ def quality(smoke: bool = False, seed: int = 0,
         shadow_samples=online["queries"],
         offline_recall=round(offline, 4),
         recall_gap=round(abs(online["recall"] - offline), 4),
-        cost_records=len(recs),
-        costlog_path=costlog_path,
         slo=dict(latency_target_ms=round(target_s * 1e3, 1),
                  fault_free_alerts=sum(slo_ff.alert_counts().values()),
                  wedged_alerts=sum(slo_wedged.alert_counts().values()),
@@ -488,7 +470,6 @@ def quality(smoke: bool = False, seed: int = 0,
     print(f"[serve] quality: online={row['online_recall']} "
           f"offline={row['offline_recall']} gap={row['recall_gap']} "
           f"({row['shadow_samples']} shadow samples) "
-          f"cost_records={row['cost_records']} "
           f"slo_alerts=ff:{row['slo']['fault_free_alerts']}/"
           f"wedged:{row['slo']['wedged_alerts']} "
           f"overhead_ratio={overhead['ratio']}", flush=True)
@@ -501,18 +482,6 @@ def quality(smoke: bool = False, seed: int = 0,
         f"quality: online estimate {online['recall']:.3f} vs offline "
         f"{offline:.3f} over the same served queries (gap > 0.05)"
     )
-    assert len(recs) > 0, "quality: the cost log is empty"
-    for key in ("v", "seq", "latency_s", "spans", "pipeline",
-                "effective_pipeline", "query", "index", "counts"):
-        assert key in recs[0], (
-            f"quality: cost record is missing {key!r}: {sorted(recs[0])}"
-        )
-    assert recs[0]["pipeline"] == "two_stage" and \
-        recs[0]["index"]["store"] == "int8" and \
-        "code_format" in recs[0]["index"], (
-            f"quality: cost record carries the wrong plan features: "
-            f"{recs[0]}"
-        )
     assert sum(slo_ff.alert_counts().values()) == 0, (
         f"quality: SLO burn alert fired on the fault-free leg: "
         f"{slo_ff.events()}"

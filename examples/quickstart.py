@@ -21,9 +21,8 @@ fault-tolerant tier (health-checked replica pool + retry/hedge router,
 DESIGN.md §3.10) and ``--faults "wedge:r1@20+8"`` to watch it route around
 a deterministically injected fault. For the observability surface add
 ``--shadow-sample 8`` (online recall estimate with a Wilson interval,
-re-answered exactly off the hot path), ``--trace-sample 16 --cost-log
-experiments/costlog.jsonl`` (one JSONL plan-cost record per traced
-request), ``--slo-p99-ms 50`` (multi-rate error-budget burn alerts) and
+re-answered exactly off the hot path), ``--trace-sample 16`` (the
+slowest sampled request's span tree at exit), ``--slo-p99-ms 50`` (multi-rate error-budget burn alerts) and
 ``--dash`` (live terminal dashboard); ``python -m repro.obs.report
 --metrics experiments/serve_metrics.json`` renders a dump offline
 (DESIGN.md §3.12).

@@ -125,5 +125,6 @@ def swap_deltas_pallas(
         out_specs=pl.BlockSpec((kp, gc), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, gc), jnp.float32),
         interpret=interpret,
+        name="swap_deltas_pallas",
     )(Dp, d1p, d2p, n1p, vp)
     return out[:k, :g]

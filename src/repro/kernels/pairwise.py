@@ -187,6 +187,7 @@ def pairwise_pallas(
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
+            name="pairwise_pallas",
         )(Xp, Yp, xx, yy)
 
     kernel = functools.partial(_vpu_kernel, form=form, nk=gk)
@@ -201,4 +202,5 @@ def pairwise_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="pairwise_pallas",
     )(Xp, Yp)

@@ -185,6 +185,7 @@ def scan_pallas(
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
         ],
         interpret=interpret,
+        name="scan_pallas",
     )(Qp, Cp, Sp, okp)
     # Same slot contract as rank_pallas: masked/short rows must not leak
     # out-of-range indices to host-side consumers.

@@ -185,6 +185,7 @@ def knn_pallas(
             jax.ShapeDtypeStruct((qp, k), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_pallas",
     )(Qp, DBp)
     return dists[:nq], ids[:nq]
 
@@ -345,6 +346,7 @@ def rank_pallas(
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
         ],
         interpret=interpret,
+        name="rank_pallas",
     )(*in_arrays)
     # Honour the slot contract (in [0, w)) even for masked/short rows: the
     # -1 init and padded columns rank as BIG but must not leak out-of-range

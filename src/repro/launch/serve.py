@@ -28,9 +28,8 @@ around).
 Quality & SLO observability (DESIGN.md §3.12): ``--shadow-sample N``
 re-answers 1 served query in N exactly on a background worker and prints
 the online recall estimate (with its Wilson interval) at exit;
-``--cost-log PATH`` appends one JSONL cost record per traced request
-(requires ``--trace-sample``); ``--slo-p99-ms`` / ``--slo-recall-floor``
-attach an SLO tracker with multi-rate burn alerts (replicated path);
+``--slo-p99-ms`` / ``--slo-recall-floor`` attach an SLO tracker with
+multi-rate burn alerts (replicated path);
 ``--dash`` renders a live terminal dashboard while serving; and
 ``--trace-dump PATH`` writes the retained sampled traces as JSON at exit
 (both serve paths — feed it to ``python -m repro.obs.report``).
@@ -134,9 +133,6 @@ def parse_args(argv=None):
                         "it exactly off the hot path; prints the online "
                         "recall estimate with its Wilson interval at exit "
                         "(0 = off)")
-    p.add_argument("--cost-log", default=None, metavar="PATH",
-                   help="append one JSONL plan-cost record per traced "
-                        "request to PATH (needs --trace-sample)")
     p.add_argument("--slo-p99-ms", type=float, default=None,
                    help="SLO latency target: at most 1%% of requests may "
                         "take longer (replicated path)")
@@ -184,11 +180,10 @@ def _serve_replicated(args, idx, kernel, train, test) -> int:
             recall_floor=args.slo_recall_floor,
             window_s=args.slo_window_s,
         ))
-    costlog = obs.CostLog(args.cost_log) if args.cost_log else None
     router = Router(replica_set, RouterConfig(
         deadline_s=args.deadline_ms / 1e3, seed=args.seed,
         trace_every=args.trace_sample, shadow_every=args.shadow_sample),
-        slo=slo, costlog=costlog)
+        slo=slo)
     print(f"[serve] replicated tier: {args.replicas} replicas"
           + (f", faults={args.faults}" if plan else ", fault-free"))
     router.search(test[0])  # warmup compile (every replica shares the jits)
@@ -255,10 +250,6 @@ def _serve_replicated(args, idx, kernel, train, test) -> int:
         print(f"[serve] SLO status: {slo.status()}")
         for ev in slo.events():
             print(f"[serve]   slo event: {ev}")
-    if costlog is not None:
-        costlog.close()
-        print(f"[serve] wrote {len(costlog)} cost records "
-              f"to {args.cost_log}")
     if args.trace_sample:
         ex = router.traces.exemplar()
         if ex is not None:
@@ -393,13 +384,12 @@ def serve_engine(args, idx, kernel, train, test) -> dict:
     # created at submit time (there is no router in front), the engine
     # records queue/batch/execute spans under its root.
     sampler = obs.TraceSampler(args.trace_sample)
-    # Shadow recall estimation + cost recording (DESIGN.md §3.12): no
-    # router here, so the driver feeds both directly from the query loop.
+    # Shadow recall estimation (DESIGN.md §3.12): no router here, so the
+    # query loop below feeds it directly.
     est = None
     if args.shadow_sample:
         est = obs.RecallEstimator(handle if handle is not None else idx,
                                   every_n=args.shadow_sample)
-    costlog = obs.CostLog(args.cost_log) if args.cost_log else None
     dash = obs.Dashboard(quality=est) if args.dash else None
 
     rng = np.random.default_rng(args.seed)
@@ -444,8 +434,6 @@ def serve_engine(args, idx, kernel, train, test) -> dict:
                         pipeline=handler.describe()["effective_pipeline"])
         if tr is not None:
             tr.finish(outcome="ok")
-            if costlog is not None:
-                costlog.record(tr, handler.describe())
     engine.close()
     if dash is not None:
         dash.close()
@@ -487,10 +475,6 @@ def serve_engine(args, idx, kernel, train, test) -> dict:
                  f"samples" if e["recall"] is not None
                  else "no samples answered"))
         est.close()
-    if costlog is not None:
-        costlog.close()
-        print(f"[serve] wrote {len(costlog)} cost records "
-              f"to {args.cost_log}")
     if args.trace_sample:
         ex = sampler.buffer.exemplar()
         if ex is not None:

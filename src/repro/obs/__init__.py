@@ -49,7 +49,6 @@ from repro.obs.trace import (
     span,
 )
 from repro.obs.quality import RecallEstimator, wilson
-from repro.obs.costlog import CostLog, build_record, load_costlog
 from repro.obs.slo import SLOSpec, SLOTracker
 from repro.obs.report import Dashboard, build_report, render_dashboard
 
@@ -81,12 +80,9 @@ __all__ = [
     "active_spans",
     "is_tracing",
     "span",
-    # quality / cost / SLO / report (DESIGN.md §3.12)
+    # quality / SLO / report (DESIGN.md §3.12)
     "RecallEstimator",
     "wilson",
-    "CostLog",
-    "build_record",
-    "load_costlog",
     "SLOSpec",
     "SLOTracker",
     "Dashboard",

@@ -76,7 +76,6 @@ PLAN_COMPILES = "plan_compiles_total"
 PLAN_CACHE_HITS = "plan_cache_hits_total"
 PLAN_REPLANS = "plan_replans_total"
 PLAN_EXECUTIONS = "plan_executions_total"
-PLAN_COST_RECORDS = "plan_cost_records_total"
 
 # --------------------------------------------------------------------------
 # store — the tiered leaf store's out-of-core payload (store/leaf_store.py)
@@ -168,7 +167,9 @@ CATALOGUE: dict[str, tuple[str, str]] = {
     ENGINE_BATCH_OCCUPANCY: ("histogram", "valid rows / batch_size per batch"),
     ENGINE_QUEUE_DEPTH: ("gauge", "requests queued when a batch was taken"),
     ENGINE_QUEUE_WAIT: ("histogram", "enqueue -> taken-into-batch wait"),
-    ENGINE_HANDLER_TIME: ("histogram", "handler call duration per batch"),
+    ENGINE_HANDLER_TIME: ("histogram", "per batch, from the handler's "
+                                       "dispatch to the results delivered "
+                                       "to every request"),
     ROUTER_REQUESTS: ("counter", "requests admitted by the router"),
     ROUTER_DISPATCHES: ("counter", "attempts dispatched, by replica"),
     ROUTER_RETRIES: ("counter", "re-dispatches after a failed attempt"),
@@ -186,8 +187,6 @@ CATALOGUE: dict[str, tuple[str, str]] = {
     PLAN_CACHE_HITS: ("counter", "plan-cache hits, by pipeline"),
     PLAN_REPLANS: ("counter", "stale-fingerprint transparent replans"),
     PLAN_EXECUTIONS: ("counter", "plan executions, by pipeline"),
-    PLAN_COST_RECORDS: ("counter", "plan-execution cost records appended "
-                                   "to the cost log"),
     STORE_FETCHES: ("counter", "granules fetched from the exact payload"),
     STORE_HITS: ("counter", "granule requests served from the LRU"),
     STORE_FETCH_BYTES: ("counter", "bytes fetched from the exact payload"),

@@ -481,9 +481,16 @@ class BatchingEngine:
         # After close() the worker drains requests already enqueued (they
         # hold waiting callers) before exiting; _take_batch cannot block
         # here because a non-empty queue returns promptly.
+        #
+        # Each stage of a batch is one obs span (a profiler annotation on
+        # this thread): engine.take_batch, engine.stack, engine.dispatch,
+        # engine.device_wait, engine.fetch, engine.deliver. They open
+        # outside the sampled requests' active set, so the sampled span
+        # trees keep their shape.
         while (not self._stop.is_set() or not self._q.empty()
                or self._pending):
-            batch = self._take_batch()
+            with obs.span("engine.take_batch"):
+                batch = self._take_batch()
             if not batch:
                 continue
             if batch[0].kind in _WRITE_KINDS:
@@ -500,9 +507,12 @@ class BatchingEngine:
             n = len(batch)
             handler = (self.handler if batch[0].kind == "search"
                        else self.extra_handlers[batch[0].kind])
-            pad = self.pad_payload if self.pad_payload is not None else batch[0].payload
-            rows = [r.payload for r in batch] + [pad] * (self.batch_size - n)
-            stacked = jax.tree.map(lambda *xs: np.stack(xs), *rows)
+            with obs.span("engine.stack"):
+                pad = (self.pad_payload if self.pad_payload is not None
+                       else batch[0].payload)
+                rows = ([r.payload for r in batch]
+                        + [pad] * (self.batch_size - n))
+                stacked = jax.tree.map(lambda *xs: np.stack(xs), *rows)
             # Tracing: a batch serves many requests, several of which may
             # be sampled. Each traced request gets queue_wait / batch_wait
             # children (backdated from its own stamps) plus an execute
@@ -523,11 +533,21 @@ class BatchingEngine:
                     "execute", kind=batch[0].kind, batch=n,
                     engine=self.name))
             try:
-                if exec_spans:
-                    with obs.activate(exec_spans):
+                # dispatch returns once the batch is enqueued on the
+                # device; the one wait for its results is device_wait,
+                # then one device->host copy per result array
+                with obs.span("engine.dispatch"):
+                    if exec_spans:
+                        with obs.activate(exec_spans):
+                            results = handler(stacked, n)
+                    else:
                         results = handler(stacked, n)
-                else:
-                    results = handler(stacked, n)
+                for s in exec_spans:
+                    s.end()
+                with obs.span("engine.device_wait"):
+                    jax.block_until_ready(results)
+                with obs.span("engine.fetch"):
+                    results = jax.device_get(results)
             except BaseException as e:  # noqa: BLE001 — a handler failure
                 # fails this batch (each wait() re-raises), never the worker:
                 # a dead worker would silently hang every queued and future
@@ -541,14 +561,12 @@ class BatchingEngine:
                 self._m_handler_errors.inc()
                 self._finish_batch_metrics(batch, n, t_exec)
                 continue
-            for s in exec_spans:
-                s.end()
-            for i, r in enumerate(batch):
-                r._finish(result=jax.tree.map(
-                    lambda a: np.asarray(a)[i], results))
-            self._bump(batches=1, requests=n,
-                       occupancy_sum=n / self.batch_size)
-            self._finish_batch_metrics(batch, n, t_exec)
+            with obs.span("engine.deliver"):
+                for i, r in enumerate(batch):
+                    r._finish(result=jax.tree.map(lambda a: a[i], results))
+                self._bump(batches=1, requests=n,
+                           occupancy_sum=n / self.batch_size)
+                self._finish_batch_metrics(batch, n, t_exec)
 
     def _finish_batch_metrics(self, batch, n, t_exec):
         self._m_batches.inc()
@@ -613,8 +631,7 @@ class QueryHandler:
         return self.current.plan(self.query)
 
     def describe(self) -> dict:
-        """Plan features for the next batch (``SearchPlan.describe()``) —
-        what the cost log joins against measured span timings."""
+        """Plan features for the next batch (``SearchPlan.describe()``)."""
         return self.plan().describe()
 
     def __call__(self, batch, n_valid):

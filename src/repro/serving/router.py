@@ -174,17 +174,15 @@ class Router:
 
     def __init__(self, replica_set: ReplicaSet,
                  config: Optional[RouterConfig] = None, *,
-                 quality=None, slo=None, costlog=None):
+                 quality=None, slo=None):
         self.set = replica_set
         self.cfg = config or RouterConfig()
-        # Quality/SLO/cost observability (DESIGN.md §3.12), all optional:
+        # Quality/SLO observability (DESIGN.md §3.12), both optional:
         # ``quality`` is an obs.RecallEstimator (built here when
         # cfg.shadow_every > 0 and none is passed), ``slo`` an
         # obs.SLOTracker fed from every request completion and evaluated
-        # by the prober thread, ``costlog`` an obs.CostLog appended for
-        # each traced (sampled) request.
+        # by the prober thread.
         self.slo = slo
-        self.costlog = costlog
         self._own_quality = False
         if quality is None and self.cfg.shadow_every > 0:
             from repro.obs.quality import RecallEstimator
@@ -466,7 +464,7 @@ class Router:
                             retries=rr.retries, hedged=rr.hedged)
                     dists, ids = req.result
                     ids = np.asarray(ids)
-                    self._observe_success(rr, rep, lat, ids)
+                    self._observe_success(rr, lat, ids)
                     return RouterResult(
                         dists=np.asarray(dists), ids=ids,
                         replica=rep.id, degraded=(rr.kind == "degraded"),
@@ -551,13 +549,13 @@ class Router:
             rr._evt.clear()
             rr._evt.wait(max(0.0, min(wake) - time.time()))
 
-    # -- quality / SLO / cost hooks (DESIGN.md §3.12) --------------------------
+    # -- quality / SLO hooks (DESIGN.md §3.12) --------------------------------
 
-    def _observe_success(self, rr: RouterRequest, rep, lat: float,
+    def _observe_success(self, rr: RouterRequest, lat: float,
                          ids) -> None:
-        """Feed a served request into the SLO tracker, the shadow recall
-        estimator, and (when traced) the cost log. Telemetry never kills a
-        request: failures here are swallowed, not raised."""
+        """Feed a served request into the SLO tracker and the shadow recall
+        estimator. Telemetry never kills a request: failures here are
+        swallowed, not raised."""
         try:
             if self.slo is not None:
                 self.slo.record_request(lat, ok=True)
@@ -566,11 +564,6 @@ class Router:
                     rr.seq, rr.payload, ids,
                     pipeline=self._pipeline_label(rr.kind),
                     leg="degraded" if rr.kind == "degraded" else "normal")
-            if self.costlog is not None and rr.trace is not None:
-                self.costlog.record(
-                    rr.trace, self._describe_for(rr.kind),
-                    replica=rep.id, degraded=(rr.kind == "degraded"),
-                    retries=rr.retries, hedged=rr.hedged)
         except Exception:
             pass
 

@@ -277,8 +277,15 @@ def test_span_mirroring_and_nesting():
         assert stage.name == "stage" and stage.attrs == {"n": 1}
         (inner,) = stage.children
         assert inner.name == "inner" and inner.t1 is not None
-    # inactive: the shared no-op, zero allocation
-    assert obs.span("whatever") is obs.span("whatever")
+    # inactive: only the profiler annotation; no span tree is touched
+    from jax.profiler import TraceAnnotation
+
+    bare = obs.span("whatever")
+    assert isinstance(bare, TraceAnnotation)
+    with bare:
+        assert not obs.is_tracing()
+    for tr in (t1, t2):
+        assert [c.name for c in tr.root.children] == ["stage"]
 
 
 # --------------------------- end-to-end span tree ----------------------------

@@ -1,16 +1,14 @@
 """Quality & SLO observability (DESIGN.md §3.12): Wilson intervals,
 shadow recall estimation against exhaustive recall on a seeded workload
-(with degraded-leg attribution), multi-rate SLO burn alerts, the
-plan-cost recorder round-trip, and the report/dashboard surface."""
+(with degraded-leg attribution), multi-rate SLO burn alerts, and the
+report/dashboard surface."""
 
-import json
 import time
 
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.obs import costlog as costlog_lib
 from repro.obs import report as report_lib
 from repro.obs.metrics import MetricsRegistry
 
@@ -218,70 +216,6 @@ def test_slo_availability_and_recall_objectives():
     slo.evaluate()
     counts = slo.alert_counts()
     assert counts.get("availability") == 1 and counts.get("recall") == 1
-
-
-# --------------------------- plan-cost recorder ------------------------------
-
-
-def _make_trace():
-    tr = obs.TraceSampler(1).sample("request", 8, kind="search")
-    attempt = tr.root.child("attempt", replica=0)
-    scan = attempt.child("scan", candidates=64)
-    scan.end()
-    rerank = attempt.child("rerank", rows=32)
-    rerank.end()
-    attempt.end(outcome="won")
-    tr.finish(outcome="ok")
-    return tr
-
-
-_DESCRIBE = dict(
-    pipeline="two_stage", effective_pipeline="two_stage",
-    query=dict(k=10, beam=32, rerank_width=64),
-    capabilities=dict(n_levels=2, store="int8", payload_released=True),
-    index=dict(n_points=500, code_format="int8"),
-    kernel=dict(bm=64),
-)
-
-
-def test_build_record_joins_plan_features_with_span_costs():
-    rec = costlog_lib.build_record(_make_trace(), _DESCRIBE,
-                                   dict(replica=0))
-    assert rec["v"] == costlog_lib.SCHEMA_VERSION
-    assert rec["seq"] == 8 and rec["outcome"] == "ok"
-    assert rec["latency_s"] > 0
-    assert set(rec["spans"]) == {"request", "attempt", "scan", "rerank"}
-    assert rec["spans"]["scan"]["count"] == 1
-    assert rec["counts"] == dict(candidates=64, rows=32)
-    assert rec["pipeline"] == "two_stage"
-    assert rec["index"] == dict(n_points=500, n_levels=2,
-                                code_format="int8", store="int8",
-                                payload_released=True)
-    assert rec["kernel"] == dict(bm=64)
-    assert rec["replica"] == 0
-    # works on the exported dict form too (the offline path)
-    rec2 = costlog_lib.build_record(_make_trace().to_dict(), _DESCRIBE)
-    assert rec2["counts"] == rec["counts"]
-
-
-def test_costlog_roundtrips_through_jsonl(tmp_path):
-    path = tmp_path / "cost.jsonl"
-    log = obs.CostLog(str(path))
-    assert len(log) == 0 and not path.exists()  # lazy open
-    for _ in range(3):
-        log.record(_make_trace(), _DESCRIBE, degraded=False)
-    log.close()
-    recs = costlog_lib.load(str(path))
-    assert len(recs) == len(log) == 3
-    for rec in recs:
-        for key in ("v", "seq", "latency_s", "outcome", "pipeline",
-                    "effective_pipeline", "query", "index", "kernel",
-                    "spans", "counts", "degraded"):
-            assert key in rec, key
-        json.dumps(rec)  # every line is plain JSON
-    # the records counter tracked every append
-    snap = obs.snapshot()
-    assert snap[obs.names.PLAN_COST_RECORDS]["series"][0]["value"] == 3
 
 
 # --------------------------- report CLI + dashboard --------------------------

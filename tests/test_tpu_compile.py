@@ -13,6 +13,7 @@ test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,3 +103,39 @@ def test_swap_deltas_compiles(one_chip):
             D_, d1, d2, n1, v, k=k),
         S((g, g)), S((g,)), S((g,)), S((g,), jnp.int32), S((g,), jnp.bool_),
     )
+
+
+def test_kernel_instruction_names_are_stable(one_chip):
+    """A device trace names each op by its HLO instruction, and the
+    benchmark finds the kernels by those names. Compile an outer program
+    that gathers candidate rows and ranks them at a descent level and at
+    the leaf (as ``nsa.search_beam`` does) and scans the gathered int8
+    codes (as the two-stage scan does): every kernel instruction must be
+    ``rank_pallas.N`` or ``scan_pallas.N``, whatever wraps the call."""
+    b, n = 32, 1 << 16
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+
+    def serve(q, points, norms, idx_level, ok_level, idx_leaf, ok_leaf,
+              codes, scales):
+        out = []
+        for idx, ok, k in ((idx_level, ok_level, 32), (idx_leaf, ok_leaf, 10)):
+            out += topk.rank_pallas(q, jnp.take(points, idx, axis=0), ok,
+                                    jnp.take(norms, idx), form="l2", k=k)
+        out += quantized.scan_pallas(
+            q, jnp.take(codes, idx_leaf, axis=0),
+            jnp.take(scales, idx_leaf // 128), ok_leaf, form="l2", k=128)
+        return out
+
+    text = jax.jit(serve).lower(
+        S((b, D)), S((n, D)), S((n,)), S((b, 1024), jnp.int32),
+        S((b, 1024), jnp.bool_), S((b, 512), jnp.int32),
+        S((b, 512), jnp.bool_), S((n, D), jnp.int8), S((n // 128,)),
+    ).compile().as_text()
+    names = [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(re.sub(r"\.\d+$", "", x) for x in names) == [
+        "rank_pallas", "rank_pallas", "scan_pallas"]
+    for x in names:
+        assert re.match(r"^rank_pallas(\.\d+)?$", x) or \
+            re.match(r"^scan_pallas(\.\d+)?$", x), x
