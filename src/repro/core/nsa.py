@@ -206,12 +206,16 @@ def _descend_beam(
     beams: tuple,
     max_children: tuple,
     kernel: kops.KernelConfig,
-) -> tuple[Array, Array]:
+) -> tuple[Array, Array, Array]:
     """Levels L..1 of the batched beam search: per level one gather + one
     fused rank. Returns the leaf candidate table ``(cand_idx [B, W],
     cand_ok [B, W])`` — the input of the leaf ranking stage, whichever
     payload tier performs it (the fused fp32 rank of :func:`search_beam`, or
-    the quantised scan -> exact rerank of ``repro.store.two_stage``).
+    the quantised scan -> exact rerank of ``repro.store.two_stage``) — and
+    the child runs it is made of, ``starts [B, beam]``: candidate
+    ``j * mc + t`` is row ``starts[:, j] + t``. Each level's rank is given
+    its runs, so a column-major table is ranked where it lies
+    (``ops.rank_gathered``).
 
     The radius filter is applied *after* the beam selection: candidates
     sort ascending by distance, so every in-radius candidate outranks every
@@ -246,7 +250,7 @@ def _descend_beam(
             beam = min(beams[l], W)
             d_sel, slot = kops.rank_gathered(  # [B, beam] fused rank
                 Q, lv.points, lv.sq_norm, cand_idx, cand_ok, dist, k=beam,
-                config=kernel,
+                run_starts=starts, config=kernel,
             )
             sel_idx = jnp.take_along_axis(cand_idx, slot, axis=1)
         sel_ok = (d_sel < radii[l]) & (d_sel < BIG / 2)
@@ -261,7 +265,7 @@ def _descend_beam(
         n_lower = levels[l - 1].points.shape[0]
         cand_idx = jnp.clip(grid.reshape(B, beam * mc), 0, n_lower - 1)
         cand_ok = gvalid.reshape(B, beam * mc)
-    return cand_idx, cand_ok
+    return cand_idx, cand_ok, starts
 
 
 @functools.partial(
@@ -296,10 +300,11 @@ def descend_beam(
         )
         cand_ok = jnp.broadcast_to(index.levels[0].valid[None, :], (B, n0))
         return cand_idx, cand_ok
-    return _descend_beam(
+    cand_idx, cand_ok, _ = _descend_beam(
         index, dist, Q, radii, beams, tuple(max_children),
         kernel or kops.DEFAULT,
     )
+    return cand_idx, cand_ok
 
 
 def assemble_result(
@@ -359,7 +364,7 @@ def _search_beam_batch(
         neg, slot = jax.lax.top_k(-D_top, k_eff)
         dists, slots = -neg, slot.astype(jnp.int32)
     else:
-        cand_idx, cand_ok = _descend_beam(
+        cand_idx, cand_ok, starts = _descend_beam(
             index, dist, Q, radii, beams, max_children, kernel
         )
         W = cand_idx.shape[1]
@@ -367,7 +372,7 @@ def _search_beam_batch(
         k_eff = min(k, W)
         dists, slot = kops.rank_gathered(  # fused leaf ranking
             Q, leaf.points, leaf.sq_norm, cand_idx, ok, dist, k=k_eff,
-            config=kernel,
+            run_starts=starts, config=kernel,
         )
         slots = jnp.take_along_axis(cand_idx, slot, axis=1)
     # Candidates counted are those *examined* (the pruning metric). The fused

@@ -47,6 +47,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout
 
 from repro.kernels import autotune as _at
 from repro.kernels import kmedoids as _kmk
@@ -89,6 +91,32 @@ def resolve_form(distance) -> Optional[str]:
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _layout_device():
+    """The device whose default layouts a traced program receives."""
+    return jax.devices()[0]
+
+
+def column_major(table) -> bool:
+    """Whether the backend stores a 2-D table of this shape column-major.
+
+    A jitted search receives each index table in the backend's default
+    layout for its shape. The TPU picks the tighter tiling: ``f32[n, 100]``
+    is stored column-major (100 features pad to 104 sublanes: 416 B a row,
+    against 512 B row-major), while ``f32[n, 256]`` and tables of a few
+    hundred rows are stored row-major. Backends without layouts (and the
+    CPU) read as row-major.
+    """
+    if table.ndim != 2:
+        return False
+    dev = _layout_device()
+    try:
+        pjrt = dev.client.get_default_layout(
+            np.dtype(table.dtype), tuple(table.shape), dev)
+    except jax.errors.JaxRuntimeError:  # backend without layout queries
+        return False
+    return Layout.from_pjrt_layout(pjrt).major_to_minor == (1, 0)
 
 
 def _fp(force_pallas: Optional[bool], config: Optional[KernelConfig]) -> bool:
@@ -371,6 +399,7 @@ def rank_gathered(
     *,
     k: int,
     slot_valid: Optional[Array] = None,
+    run_starts: Optional[Array] = None,
     bq: Optional[int] = None,
     bn: Optional[int] = None,
     force_pallas: Optional[bool] = None,
@@ -386,8 +415,17 @@ def rank_gathered(
     (``ref.fold_slot_valid``) — deleted rows rank as ``BIG`` on every path
     (gemm+gather, gathered cube, Pallas) without touching ``points``.
 
+    ``run_starts``: optional int32 [b, r] when the candidates are child
+    runs, as the beam descent makes them: ``cand_idx[:, j * mc + t] ==
+    clip(run_starts[:, j] + t, 0, n - 1)`` with ``mc = w // r``.
+
     Dispatch picks the cheapest way to avoid the [b, w, d] gathered cube:
 
+    * TPU / force_pallas, child runs of a table stored column-major
+      (:func:`column_major`) — ``rank_runs_pallas``: the runs' lane blocks
+      are fetched from the table where it lies and ranked in the same
+      kernel. A row gather would make XLA relayout the whole table first,
+      on every call.
     * TPU / force_pallas — row gather + the fused ``rank_pallas`` kernel
       (candidate blocks stream through VMEM; the [b, w] distance matrix
       never reaches HBM).
@@ -415,12 +453,26 @@ def rank_gathered(
         d = jnp.where(cand_ok, d, _ref.BIG)
         neg, slots = jax.lax.top_k(-d, k)
         return -neg, slots.astype(jnp.int32)
-    C = jnp.take(points, cand_idx, axis=0)  # [b, w, d]
     cc = (
         jnp.take(sq_norms, cand_idx)
         if form in _ref.NORM_FORMS and sq_norms is not None
         else None
     )
+    if (
+        run_starts is not None
+        and form is not None
+        and (_on_tpu() or fp)
+        and points.dtype == jnp.float32
+        and w // run_starts.shape[1] <= _tiling.LANE
+        and _tiling.vmem_rank_runs(points.shape[1]) <= _tiling.VMEM_BUDGET
+        and (cc is not None or form not in _ref.NORM_FORMS)
+        and column_major(points)
+    ):
+        return _tk.rank_runs_pallas(
+            Q, points.T, run_starts, cand_ok, cc,
+            form=form, k=k, interpret=not _on_tpu(),
+        )
+    C = jnp.take(points, cand_idx, axis=0)  # [b, w, d]
     return rank_candidates(
         Q, C, cand_ok, distance, k=k, c_sq_norms=cc,
         bq=bq, bn=bn, force_pallas=fp, config=config,

@@ -120,6 +120,17 @@ def vmem_rank(bq: int, bn: int, d: int, k: int, itemsize: int = 4,
             + 3 * bq * (k + bn) * 4)
 
 
+RUNS_GROUP = 8  # queries per grid step of the child-run rank (f32 sublanes)
+
+
+def vmem_rank_runs(d: int) -> int:
+    """Child-run rank of f32 rows: two double-buffered [d, 128] table blocks
+    and one double-buffered lane-broadcast [d, 128] query per query of the
+    group, and the [d, 256] working set of one run."""
+    block = ceil_to(d, 8) * LANE * 4
+    return 2 * 3 * RUNS_GROUP * block + 4 * 2 * block
+
+
 def vmem_swap(bg: int, g: int, k: int) -> int:
     gc = ceil_to(g, LANE)
     return 3 * bg * gc * 4 + 2 * ceil_to(k, 8) * gc * 4

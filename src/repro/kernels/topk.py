@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tiling
 from repro.kernels.ref import BIG, FORMS, GRAM_FORMS, NORM_FORMS, PRECISION
@@ -233,9 +234,15 @@ def _rank_tile_distance(form: str, qs, cs, cc=None) -> Array:
     if form not in GRAM_FORMS:
         return acc[0]
     g, qq, cc_planes = acc
+    return _gram_distance(form, g, qq, cc_planes if cc is None else cc)
+
+
+def _gram_distance(form: str, g, qq, cc) -> Array:
+    """A Gram form's distance from the dot products ``g`` [bq, bn], the
+    query norms ``qq`` [bq, 1] and the candidate norms ``cc`` [bq, bn]."""
     if form == "dot":
         return -g
-    cc = cc_planes if cc is None else cc.astype(jnp.float32)
+    cc = cc.astype(jnp.float32)
     if form in ("sqeuclidean", "l2"):
         d2 = jnp.maximum(qq + cc - 2.0 * g, 0.0)
         return d2 if form == "sqeuclidean" else jnp.sqrt(d2)
@@ -351,4 +358,160 @@ def rank_pallas(
     # Honour the slot contract (in [0, w)) even for masked/short rows: the
     # -1 init and padded columns rank as BIG but must not leak out-of-range
     # indices to host-side consumers (a NumPy per-row gather would wrap them).
+    return dists[:b], jnp.clip(slots[:b], 0, w - 1)
+
+
+# ---------------------------------------------------------------------------
+# Child runs ranked where a column-major table lies (the beam descent)
+# ---------------------------------------------------------------------------
+
+
+def _rank_runs_kernel(s_ref, q_ref, qb_ref, *refs, form, k, g, mc, r):
+    # refs: a (first, second) lane block of the table per query of the
+    # group, ok [g, wp], cc [g, wp] (norm forms), the two outputs, and the
+    # [wp / 128 + 1, g, 128] scratch the runs' partial distances gather in.
+    blocks, rest = refs[:2 * g], refs[2 * g:]
+    if form in NORM_FORMS:
+        ok_ref, cc_ref, od_ref, oi_ref, acc_ref = rest
+    else:
+        (ok_ref, od_ref, oi_ref, acc_ref), cc_ref = rest, None
+    i, j = pl.program_id(0), pl.program_id(1)
+    lane = tiling.LANE
+    col = j * mc  # the run's first slot on the candidate axis
+    dest, v = col % lane, col // lane
+    row = jax.lax.broadcasted_iota(jnp.int32, (g, 2 * lane), 0)
+    parts = jnp.zeros((g, 2 * lane), jnp.float32)
+    for q in range(g):
+        # [d, 256] lanes of the run's two blocks against the query as a
+        # column: the feature axis reduces over sublanes.
+        two = jnp.concatenate([blocks[2 * q][...], blocks[2 * q + 1][...]],
+                              axis=1).astype(jnp.float32)
+        qc = qb_ref[q].astype(jnp.float32)
+        qc = jnp.concatenate([qc, qc], axis=1)
+        if form in GRAM_FORMS:
+            part = jnp.sum(two * qc, axis=0, keepdims=True)
+        elif form == "l1":
+            part = jnp.sum(jnp.abs(two - qc), axis=0, keepdims=True)
+        else:
+            part = jnp.max(jnp.abs(two - qc), axis=0, keepdims=True)
+        # Rotate the run's first lane to its slot's lane.
+        s = s_ref[i * g + q, j]
+        part = pltpu.roll(part, (dest - s % lane + 2 * lane) % (2 * lane), 1)
+        parts = jnp.where(row == q, part, parts)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (g, 2 * lane), 1)
+    put = (lanes >= dest) & (lanes < dest + mc)
+    acc_ref[v] = jnp.where(put[:, :lane], parts[:, :lane], acc_ref[v])
+    acc_ref[v + 1] = jnp.where(put[:, lane:], parts[:, lane:], acc_ref[v + 1])
+
+    @pl.when(j == r - 1)
+    def _rank():
+        part = jnp.concatenate(
+            [acc_ref[c] for c in range(acc_ref.shape[0] - 1)], axis=1)
+        if form in GRAM_FORMS:
+            qv = q_ref[...].astype(jnp.float32)
+            qq = jnp.sum(qv * qv, axis=-1)[:, None]
+            cc = cc_ref[...] if cc_ref is not None else None
+            part = _gram_distance(form, part, qq, cc)
+        d = jnp.where(ok_ref[...].astype(jnp.int32) != 0, part, BIG)
+        od_ref[...], oi_ref[...] = merge_topk(
+            jnp.full(od_ref.shape, BIG, jnp.float32),
+            jnp.full(oi_ref.shape, -1, jnp.int32), d, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("form", "k", "interpret"))
+def rank_runs_pallas(
+    Q: Array,
+    PT: Array,
+    starts: Array,
+    ok: Array,
+    cc: Array = None,
+    *,
+    form: str,
+    k: int,
+    interpret: bool = False,
+) -> tuple[Array, Array]:
+    """:func:`rank_pallas` for child runs of a table stored column-major,
+    ranked where the table lies.
+
+    ``PT``: [d, n], the transposed view of the table: the stored bytes as
+    they lie, which XLA passes to the kernel as a bitcast. ``starts``:
+    [b, r] first row of each run; the candidate axis is the runs laid end
+    to end, ``w = r * mc`` with ``mc = ok.shape[1] // r``: slot
+    ``j * mc + t`` is row ``starts[:, j] + t``. ``ok`` / ``cc``: [b, w] as
+    in :func:`rank_pallas` (``cc`` is required for the norm forms).
+    Returns (dists[b, k] ascending, slots[b, k] into the ``w`` axis).
+
+    Grid ``(b / 8, r)``: each step the Pallas pipeline fetches, for each of
+    8 queries, the one or two [d, 128] lane blocks that hold its run
+    ``j`` (block indices from the scalar-prefetched starts). The kernel
+    reduces them against the query over the feature (sublane) axis,
+    rotates the run's ``mc`` lanes to their slots and keeps them in VMEM;
+    after the last run it ranks the [8, w] row with :func:`merge_topk`.
+    A row gather would make XLA relayout the whole table first; no [b, w,
+    d] cube reaches HBM either. Dists match :func:`rank_pallas` to float32
+    rounding: the feature sum runs in another order.
+    """
+    if form not in FORMS:
+        raise ValueError(f"unsupported form {form!r}")
+    b, d = Q.shape
+    d2, n = PT.shape
+    b2, r = starts.shape
+    w = ok.shape[1]
+    mc = w // r
+    if b != b2 or d != d2 or ok.shape[0] != b or w != r * mc:
+        raise ValueError(f"shape mismatch {Q.shape} / {PT.shape} / "
+                         f"{starts.shape} / {ok.shape}")
+    if not 0 < mc <= tiling.LANE:
+        raise ValueError(f"run length {mc} outside (0, {tiling.LANE}]")
+    if k > w:
+        raise ValueError(f"k={k} > candidate width w={w}")
+    if form in NORM_FORMS and cc is None:
+        raise ValueError(f"form {form!r} needs the candidate norms cc")
+
+    lane = tiling.LANE
+    g = tiling.RUNS_GROUP
+    bp, wp = _ceil_to(b, g), _ceil_to(w, lane)
+    nb = pl.cdiv(n, lane)
+    s0 = jnp.pad(jnp.clip(starts, 0, n - 1).astype(jnp.int32),
+                 ((0, bp - b), (0, 0)))
+    Qp = jnp.pad(Q, ((0, bp - b), (0, 0)))
+    in_arrays = [Qp, jnp.broadcast_to(Qp[:, :, None], (bp, d, lane))]
+    in_specs = [pl.BlockSpec((g, d), lambda i, j, s: (i, 0)),
+                pl.BlockSpec((g, d, lane), lambda i, j, s: (i, 0, 0))]
+    for q in range(g):
+        first = functools.partial(
+            lambda q, i, j, s: (0, s[i * g + q, j] // lane), q)
+        second = functools.partial(
+            lambda q, i, j, s: (0, jnp.minimum(s[i * g + q, j] // lane + 1,
+                                               nb - 1)), q)
+        in_arrays += [PT, PT]
+        in_specs += [pl.BlockSpec((d, lane), first),
+                     pl.BlockSpec((d, lane), second)]
+    in_arrays.append(jnp.pad(ok.astype(jnp.int8), ((0, bp - b), (0, wp - w))))
+    in_specs.append(pl.BlockSpec((g, wp), lambda i, j, s: (i, 0)))
+    if form in NORM_FORMS:
+        in_arrays.append(jnp.pad(cc.astype(jnp.float32),
+                                 ((0, bp - b), (0, wp - w))))
+        in_specs.append(pl.BlockSpec((g, wp), lambda i, j, s: (i, 0)))
+
+    kernel = functools.partial(_rank_runs_kernel, form=form, k=k, g=g, mc=mc,
+                               r=r)
+    dists, slots = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bp // g, r),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((g, k), lambda i, j, s: (i, 0)),
+                       pl.BlockSpec((g, k), lambda i, j, s: (i, 0))],
+            scratch_shapes=[pltpu.VMEM((wp // lane + 1, g, lane),
+                                       jnp.float32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bp, k), jnp.float32),
+            jax.ShapeDtypeStruct((bp, k), jnp.int32),
+        ],
+        interpret=interpret,
+        name="rank_pallas",
+    )(s0, *in_arrays)
     return dists[:b], jnp.clip(slots[:b], 0, w - 1)

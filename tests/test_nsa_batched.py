@@ -217,6 +217,84 @@ def test_search_end_to_end_force_pallas():
 
 
 # ---------------------------------------------------------------------------
+# Child runs of a column-major table: ranked where the table lies
+# ---------------------------------------------------------------------------
+
+RUN_CASES = ["block_edges", "counts", "table_end", "tombstones", "radius"]
+
+
+def _runs(case, n=300, d=20, b=5, r=4, mc=7, seed=23):
+    """Queries, a table and child runs laid out as the beam descent lays
+    them out (candidate ``j * mc + t`` is row ``starts[:, j] + t``)."""
+    rng = np.random.default_rng(seed)
+    pts = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    Q = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    starts = rng.integers(0, n - mc, size=(b, r))
+    counts = rng.integers(1, mc + 1, size=(b, r))
+    if case == "block_edges":  # runs across the 128- and 256-lane edges
+        starts[:, :3] = [[125, 250, 121], [127, 128, 0], [252, 122, 6],
+                         [124, 255, 249], [120, 256, 128]]
+        counts[:, :3] = mc
+    elif case == "counts":  # empty and full runs
+        counts[:, 0], counts[:, 1] = 0, mc
+    elif case == "table_end":  # runs in the last, partial block
+        starts[:, 0], counts[:, 0] = n - mc, mc
+        starts[:, 1], counts[:, 1] = n - 3, 3
+    starts = jnp.asarray(starts.astype(np.int32))
+    t = jnp.arange(mc)
+    idx = jnp.clip(starts[:, :, None] + t, 0, n - 1).reshape(b, r * mc)
+    ok = (t < jnp.asarray(counts)[:, :, None]).reshape(b, r * mc)
+    live = None
+    if case == "tombstones":
+        live = jnp.asarray(rng.random(n) > 0.3)
+    return Q, pts, starts, idx, ok, live
+
+
+def _assert_same_ranking(got, want):
+    """Same slots (ids) in the same order; dists equal to float32 rounding
+    (the in-place kernel sums the features in another order)."""
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-6, atol=0)
+    for a, b_ in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+@pytest.mark.parametrize("case", RUN_CASES)
+@pytest.mark.parametrize("form", ["l2", "cosine"])
+def test_runs_ranked_in_place_match_row_gather(form, case, monkeypatch):
+    """The in-place path (``rank_runs_pallas``: lane blocks of ``points.T``
+    reduced against each query, ranked in the same kernel) ranks child runs
+    as the row gather + ``rank_pallas`` does; through a whole beam search
+    too, with a radius that cuts the beams."""
+    kc = ops.KernelConfig(bq=4, bn=32, force_pallas=True)
+    if case == "radius":
+        distance = "euclidean" if form == "l2" else "cosine"
+        data, idx = _build(distance, n=300, d=8, seed=29)
+        dist = dl.get(distance)
+        Q = jnp.asarray(data[:9])
+        r = _gap_radius(idx, distance, Q, quantile=0.2)
+        run = lambda: nsa.search_beam(idx, Q, dist=dist, k=5, r=r, beam=4,
+                                      max_children=msa.max_children(idx),
+                                      kernel=kc)
+        want = run()
+        monkeypatch.setattr(ops, "column_major", lambda table: True)
+        jax.clear_caches()  # the layout is read when search_beam traces
+        got = run()
+        n_full = np.asarray(want.n_candidates)
+        assert n_full.min() < n_full.max()  # the radius cut some beams
+        _assert_same_ranking(got, want)
+        return
+    Q, pts, starts, idx, ok, live = _runs(case)
+    sq = jnp.sum(pts * pts, axis=1)
+    rank = lambda: ops.rank_gathered(Q, pts, sq, idx, ok, form, k=6,
+                                     slot_valid=live, run_starts=starts,
+                                     config=kc)
+    want = rank()
+    monkeypatch.setattr(ops, "column_major", lambda table: True)
+    _assert_same_ranking(rank(), want)
+
+
+# ---------------------------------------------------------------------------
 # Memory honesty: the dense path builds no [B, n, d] broadcast cube
 # ---------------------------------------------------------------------------
 

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import kmedoids, pairwise, quantized, topk
+from repro.core import distances, msa, nsa
+from repro.kernels import kmedoids, ops, pairwise, quantized, topk
 from repro.kernels.ref import packed_width
 
 D = 100
@@ -139,3 +140,55 @@ def test_kernel_instruction_names_are_stable(one_chip):
     for x in names:
         assert re.match(r"^rank_pallas(\.\d+)?$", x) or \
             re.match(r"^scan_pallas(\.\d+)?$", x), x
+
+
+@pytest.mark.parametrize("d", [D, 256])
+def test_search_beam_ranks_runs_where_the_tables_lie(one_chip, monkeypatch, d):
+    """Compile ``nsa.search_beam`` at batch 32, beam 32 over levels shaped
+    like the GLOVE index's (each about half the one below, child runs of
+    16-24). No level table may be copied into another layout: at d = 100
+    the large tables are stored column-major and ``rank_pallas`` fetches
+    their runs' lane blocks where they lie (a row gather would relayout each
+    whole table on every call); at d = 256 they are row-major and gathered
+    by rows. The 300-row level is row-major at both widths. Each ranked
+    level stays one ``rank_pallas`` call, which the benchmark's roofline
+    reads."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_layout_device",
+                        lambda: next(iter(one_chip.device_set)))
+    sizes, mcs = (1 << 16, 1 << 15, 1 << 14, 300, 150), (0, 24, 16, 19, 20)
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    index = msa.PDASCIndexData(
+        levels=tuple(msa.PDASCLevel(
+            points=S((n, d)), valid=S((n,), jnp.bool_),
+            parent=S((n,), jnp.int32), child_start=S((n,), jnp.int32),
+            child_count=S((n,), jnp.int32), sq_norm=S((n,)))
+            for n in sizes),
+        leaf_ids=S((sizes[0],), jnp.int32))
+    dist = distances.get("euclidean")
+    text = jax.jit(lambda index, q: nsa.search_beam(
+        index, q, dist=dist, k=10, r=1e9, beam=32, max_children=mcs),
+    ).lower(index, S((32, d))).compile().as_text()
+
+    # Every instruction that XLA names after a level table (the parameter,
+    # and any copy of it) must keep the stored layout.
+    lines = text.splitlines()
+    table = re.compile(r"= \(?f32\[\d+,\d+\]\{([\d,]+)[:}].*"
+                       r"op_name=\"index\.levels\[(\d+)\]\.points\"")
+    stored = {}
+    for line in lines:
+        m = table.search(line)
+        if m and "parameter(" in line:
+            stored[m.group(2)] = m.group(1)
+    assert len(stored) == len(sizes)
+    for line in lines:
+        m = table.search(line)
+        if m:
+            assert m.group(1) == stored[m.group(2)], f"relayout: {line}"
+
+    kernels = [re.sub(r"\.\d+$", "", re.match(
+        r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1))
+        for line in lines if "tpu_custom_call" in line]
+    ranked = len(sizes) - 1  # levels 3..1 and the leaf; the top is pairwise
+    assert sorted(kernels) == ["pairwise_pallas"] + ["rank_pallas"] * ranked
